@@ -66,26 +66,21 @@ def environment() -> dict:
     ``benchmarks/regress.py`` matches these fields before diffing two runs:
     timings from different platforms, device kinds/counts, or fast-mode
     settings are apples-to-oranges and must be refused, not averaged.
-    Device fields degrade to "none"/0 when jax is unavailable so the stamp
-    itself never fails a suite.
+    A JAX that finds no device raises here: a payload stamped with no
+    device would be a timing of nothing anyone can name.
     """
     import platform
-    env = {
+
+    import jax
+    devs = jax.devices()
+    return {
         "platform": platform.system().lower() or "unknown",
         "machine": platform.machine() or "unknown",
         "python": platform.python_version(),
         "fast": FAST,
-        "device_kind": "none",
-        "device_count": 0,
+        "device_kind": devs[0].device_kind,
+        "device_count": len(devs),
     }
-    try:
-        import jax
-        devs = jax.devices()
-        env["device_kind"] = devs[0].device_kind if devs else "none"
-        env["device_count"] = len(devs)
-    except Exception:
-        pass
-    return env
 
 # rows of the suite currently being recorded (None = recording disabled);
 # benchmarks/run.py brackets each section with begin_suite()/end_suite() so

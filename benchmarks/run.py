@@ -55,6 +55,7 @@ from __future__ import annotations
 import argparse
 import sys
 import traceback
+from pathlib import Path
 
 from . import common
 from .common import positive_int
@@ -100,6 +101,8 @@ def _parse_args():
 
 def main() -> None:
     args = _parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache(Path(__file__).resolve().parents[1])
     if args.smoke:
         # must precede the lazy section imports: they bind common.FAST then
         common.set_fast(True)

@@ -70,6 +70,22 @@ def posterior_masked(
     return mu_post, jnp.maximum(var_post, 0.0)
 
 
+def rows_dot(v: jax.Array, M: jax.Array, rows) -> jax.Array:
+    """``sum_{j < rows} v[j] * M[j]``, accumulated in row order.
+
+    A dot product's summation order is the compiler's choice, and it
+    differs between a jitted step and the same step batched under
+    ``vmap``/``scan`` (``sim_batched``) and between backends.  The
+    incremental Cholesky amplifies those last-bit differences into EIrate
+    gaps of ~1e-4, enough to flip decisions, so the fold and the readout
+    fix the order: the event engine and the batched engine then compute
+    bit-identical posteriors.  Rows from ``rows`` on are zero in every
+    caller, so bounding the loop there only skips exact ``+ 0`` terms."""
+    def body(j, acc):
+        return acc + v[j] * M[j]
+    return jax.lax.fori_loop(0, rows, body, jnp.zeros(M.shape[1:], M.dtype))
+
+
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
 def _append_step(
     W: jax.Array,
@@ -98,10 +114,10 @@ def _append_step(
     """
     # l = L^{-1} K[obs, new] is exactly column `idx` of W (rows >= k are zero).
     l = W[:, idx]
-    d2 = K_row[idx] + jitter - jnp.dot(l, l)
+    d2 = K_row[idx] + jitter - rows_dot(l, l, k)
     d = jnp.sqrt(jnp.maximum(d2, jitter))
-    w_new = (K_row - l @ W) / d
-    a_new = (z_val - mu0_val - jnp.dot(l, alpha)) / d
+    w_new = (K_row - rows_dot(l, W, k)) / d
+    a_new = (z_val - mu0_val - rows_dot(l, alpha, k)) / d
     W = jax.lax.dynamic_update_index_in_dim(W, w_new, k, axis=0)
     alpha = alpha.at[k].set(a_new)
     diag_acc = diag_acc + w_new * w_new
@@ -109,10 +125,11 @@ def _append_step(
 
 
 @jax.jit
-def _readout(W, alpha, mu0, kdiag, diag_acc):
-    # alpha @ W (not W.T @ alpha): keeps the (n, n) buffer row-major and
-    # avoids an eager 25MB transpose copy per scheduler decision.
-    mu = mu0 + alpha @ W
+def _readout(W, alpha, mu0, kdiag, diag_acc, k):
+    # alpha @ W over the k observed rows (not W.T @ alpha): keeps the
+    # (n, n) buffer row-major and avoids an eager 25MB transpose copy per
+    # scheduler decision.
+    mu = mu0 + rows_dot(alpha, W, k)
     var = jnp.maximum(kdiag - diag_acc, 0.0)
     return mu, var
 
@@ -194,7 +211,7 @@ class IncrementalGP:
         if self._kdiag is None:
             self._kdiag = jnp.diag(self.K)
         return _readout(self._W, self._alpha, self.mu0, self._kdiag,
-                        self._diag_acc)
+                        self._diag_acc, jnp.asarray(self._k))
 
     def posterior_sd(self) -> tuple[jax.Array, jax.Array]:
         mu, var = self.posterior()

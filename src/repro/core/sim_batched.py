@@ -42,7 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .ei import expected_improvement
-from .gp import DEFAULT_JITTER
+from .gp import DEFAULT_JITTER, rows_dot
 from .scheduler import POLICIES, SimResult, TrialRecord, no_obs_floor, warm_start_queue
 from .tenancy import Problem
 
@@ -271,14 +271,14 @@ def _run_batch(
             k_b = s["kcount"][b]
             K_row = Kb[b, li]
             l = Wb[:, li]
-            d2 = K_row[li] + jitter - jnp.dot(l, l)
+            d2 = K_row[li] + jitter - rows_dot(l, l, k_b)
             dchol = jnp.sqrt(jnp.maximum(d2, jitter))
-            w_new = (K_row - l @ Wb) / dchol
-            a_new = (z - mu0_b[b, li] - jnp.dot(l, ab)) / dchol
+            w_new = (K_row - rows_dot(l, Wb, k_b)) / dchol
+            a_new = (z - mu0_b[b, li] - rows_dot(l, ab, k_b)) / dchol
             Wb2 = jax.lax.dynamic_update_index_in_dim(Wb, w_new, k_b, axis=0)
             ab2 = ab.at[k_b].set(a_new)
             dacc2 = s["diag_acc"][b] + w_new * w_new
-            mu_blk = mu0_b[b] + ab2 @ Wb2
+            mu_blk = mu0_b[b] + rows_dot(ab2, Wb2, jnp.minimum(k_b + 1, m))
             var_blk = jnp.maximum(kdiag_b[b] - dacc2, 0.0)
 
             W = s["W"].at[b].set(jnp.where(do_obs, Wb2, Wb))

@@ -6,7 +6,7 @@ tenant i owning it,
     EI_i(x)   = sigma(x) * tau((mu(x) - best_i) / sigma(x)),
     score(x)  = sum_i member[i, x] * EI_i(x) / c(x),   (-inf if selected)
 
-an (N x n) pass that is pure VPU work (erf/exp) plus a tenant-axis reduction.
+an (N x n) pass that is pure VPU work (exp/divide) plus a tenant-axis reduction.
 At service scale (|L| ~ 10^4-10^5 models, N ~ 10^3 tenants) the naive path
 materializes the (N, n) EI matrix in HBM; this kernel tiles it into VMEM
 (block_users x block_models tiles, 128-lane aligned) and accumulates the
@@ -27,19 +27,35 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; accept both.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 NEG_LARGE = -1e30
 _INV_SQRT2 = 0.7071067811865476
 _INV_SQRT_2PI = 0.3989422804014327
 
+# Chebyshev fit of erfc (Press et al., Numerical Recipes, 2nd ed., §6.2
+# "erfcc"): erfc(x) = t * exp(-x^2 + P(t)), t = 1 / (1 + x/2), x >= 0, with
+# fractional error below 1.2e-7 everywhere -- Mosaic lowers no erf/erfc
+# primitive, and a relative (not absolute) bound keeps Phi(u) accurate deep
+# in the lower tail, where EI ranks the unpromising candidates.
+_ERFC_COEFFS = (-1.26551223, 1.00002368, 0.37409196, 0.09678418,
+                -0.18628806, 0.27886807, -1.13520398, 1.48851587,
+                -0.82215223, 0.17087277)
+
+
+def _norm_cdf(u):
+    """Phi(u) from exp and division only (see ``_ERFC_COEFFS``)."""
+    x = jnp.abs(u) * _INV_SQRT2
+    t = 1.0 / (1.0 + 0.5 * x)
+    poly = _ERFC_COEFFS[-1]
+    for c in _ERFC_COEFFS[-2::-1]:
+        poly = c + t * poly
+    half_erfc = 0.5 * t * jnp.exp(poly - x * x)    # Phi(-|u|)
+    return jnp.where(u < 0, half_erfc, 1.0 - half_erfc)
+
 
 def _tau_terms(u):
-    """tau(u) = u * Phi(u) + phi(u) computed from erf/exp primitives."""
-    cdf = 0.5 * (1.0 + jax.lax.erf(u * _INV_SQRT2))
+    """tau(u) = u * Phi(u) + phi(u)."""
     pdf = jnp.exp(-0.5 * u * u) * _INV_SQRT_2PI
-    return u * cdf + pdf
+    return u * _norm_cdf(u) + pdf
 
 
 def _ei_partial(mu_ref, sigma_ref, best_ref, member_ref):
@@ -115,16 +131,20 @@ def _block_topk(score_row, k: int, block_base):
     argument (DESIGN.md §10) leans on this."""
     bn = score_row.shape[1]
     iota = jax.lax.broadcasted_iota(jnp.int32, (1, bn), 1)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
     work = score_row
-    vals, idxs = [], []
-    for _ in range(k):
-        m = jnp.max(work)
-        idx = jnp.min(jnp.where(work == m, iota, jnp.int32(bn)))
-        vals.append(m)
-        idxs.append(jnp.minimum(idx, bn - 1))
+    vals = jnp.full((1, k), NEG_LARGE, jnp.float32)
+    idxs = jnp.zeros((1, k), jnp.int32)
+    # (1, 1) keepdims reductions and a lane select per round: Mosaic
+    # lowers neither 0-d reductions nor stacking scalars into a vector
+    for r in range(k):
+        m = jnp.max(work, axis=1, keepdims=True)
+        idx = jnp.min(jnp.where(work == m, iota, jnp.int32(bn)), axis=1,
+                      keepdims=True)
+        vals = jnp.where(slot == r, m, vals)
+        idxs = jnp.where(slot == r, jnp.minimum(idx, bn - 1), idxs)
         work = jnp.where(iota == idx, NEG_LARGE, work)
-    return (jnp.stack(vals)[None, :],
-            (jnp.stack(idxs)[None, :] + block_base).astype(jnp.int32))
+    return vals, idxs + block_base
 
 
 def _ei_topk_kernel(mu_ref, sigma_ref, cost_ref, selected_ref, best_ref,
@@ -142,8 +162,8 @@ def _ei_topk_kernel(mu_ref, sigma_ref, cost_ref, selected_ref, best_ref,
     @pl.when(j == pl.num_programs(1) - 1)
     def _topk_epilogue():
         vals, idxs = _block_topk(out_ref[0:1, :], k, i * bn)
-        topv_ref[0, :] = vals[0]
-        topi_ref[0, :] = idxs[0]
+        topv_ref[0] = vals
+        topi_ref[0] = idxs
 
 
 def _pad_inputs(mu, sigma, best, membership, cost, selected, bn, bN):
@@ -171,7 +191,7 @@ def eirate_pallas(
     *,
     block_models: int = 256,
     block_users: int = 256,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Returns (n,) EIrate scores, -1e30 at selected models."""
     n = mu.shape[0]
@@ -195,7 +215,7 @@ def eirate_pallas(
         ],
         out_specs=pl.BlockSpec((1, bn), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, pn), jnp.float32),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(mu_p, sg_p, cost_p, sel_p, best_p, mem_p)
@@ -215,7 +235,7 @@ def eirate_topk_pallas(
     k: int = 4,
     block_models: int = 256,
     block_users: int = 256,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """EIrate scoring with the block-local top-k epilogue: returns the
     global top-k as ``(values (k,), indices (k,))``, ties broken by lowest
@@ -243,17 +263,19 @@ def eirate_topk_pallas(
             pl.BlockSpec((bN, 1), lambda i, j: (j, 0)),
             pl.BlockSpec((bN, bn), lambda i, j: (j, i)),
         ],
+        # (num_blocks, 1, kb) with (1, 1, kb) blocks: the last two block
+        # dims equal the array's, the layout Mosaic accepts for a kb < 128
         out_specs=[
             pl.BlockSpec((1, bn), lambda i, j: (0, i)),
-            pl.BlockSpec((1, kb), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, kb), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, 1, kb), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, 1, kb), lambda i, j: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((1, pn), jnp.float32),
-            jax.ShapeDtypeStruct((pn // bn, kb), jnp.float32),
-            jax.ShapeDtypeStruct((pn // bn, kb), jnp.int32),
+            jax.ShapeDtypeStruct((pn // bn, 1, kb), jnp.float32),
+            jax.ShapeDtypeStruct((pn // bn, 1, kb), jnp.int32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(mu_p, sg_p, cost_p, sel_p, best_p, mem_p)
@@ -282,7 +304,7 @@ def eirate_classes_pallas(
     *,
     block_models: int = 256,
     block_users: int = 256,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Returns (C, n) per-class EIrate scores, -1e30 at selected models —
     the elastic device plane's 2-D (free devices x live models) matrix in
@@ -311,7 +333,7 @@ def eirate_classes_pallas(
         ],
         out_specs=pl.BlockSpec((C, bn), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((C, pn), jnp.float32),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(mu_p, sg_p, cost_p, sel_p, best_p, mem_p)

@@ -22,9 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; accept both.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -92,7 +89,7 @@ def flash_attention_pallas(
     window: int | None = None,
     block_q: int = 256,
     block_k: int = 256,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Returns (B, S, Hq, D) attention output."""
     B, S, Hq, D = q.shape
@@ -130,7 +127,7 @@ def flash_attention_pallas(
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qh, kh, vh)

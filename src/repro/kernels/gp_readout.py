@@ -25,9 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; accept both.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _readout_kernel(W_ref, alpha_ref, mu0_ref, kdiag_ref, mu_out, var_out,
                     acc_dot, acc_sq, *, emit_sd: bool = False):
@@ -63,7 +60,7 @@ def gp_readout_pallas(
     *,
     block_n: int = 512,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
     emit_sd: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """Returns (mu_post (n,), var_post (n,)) — or (mu_post, sd_post) with
@@ -103,7 +100,7 @@ def gp_readout_pallas(
             pltpu.VMEM((1, bn), jnp.float32),
             pltpu.VMEM((1, bn), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(W_p, a_p, mu0_p, kd_p)

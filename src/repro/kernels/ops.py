@@ -1,10 +1,12 @@
 """Jit'd public entry points for the Pallas kernels.
 
-Each op dispatches kernel-vs-reference by platform: the Pallas TPU kernels
-are the target implementation; on CPU (this container) they run under
-``interpret=True`` for correctness validation, while production model code
-defaults to the XLA reference path (``use_pallas=False``) because Mosaic does
-not lower on the CPU backend.
+Each op picks interpret mode by platform: the Pallas TPU kernels are the
+target implementation and run compiled on a TPU; elsewhere they run under
+``interpret=True`` for correctness validation, because Mosaic does not
+lower on the CPU backend.  The kernel entry points themselves default to
+``interpret=False``, so a caller that bypasses this module on a TPU never
+runs the interpreter by accident.  ``use_pallas=False`` selects the XLA
+reference instead.
 """
 
 from __future__ import annotations

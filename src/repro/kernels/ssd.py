@@ -24,9 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; accept both.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _ssd_kernel(xdt_ref, b_ref, c_ref, la_ref, y_ref, state, *, chunk: int):
     ci = pl.program_id(1)
@@ -72,7 +69,7 @@ def ssd_pallas(
     c: jax.Array,        # (B, S, N)
     *,
     chunk: int = 256,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Returns the SSD mix y (B, S, H, P) (without the D*x skip term)."""
     B, S, H, P = x.shape
@@ -101,7 +98,7 @@ def ssd_pallas(
         out_specs=pl.BlockSpec((1, Q, P), lambda g, ci: (g, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, S, P), jnp.float32),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(xdt_h, b, c, la_h)

@@ -16,18 +16,11 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-
-try:  # AxisType landed after jax 0.4.x; meshes default to Auto without it
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(shape))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -41,15 +34,6 @@ def make_test_mesh(data: int = 2, model: int = 2, pod: int | None = None):
     if pod:
         return _make_mesh((pod, data, model), ("pod", "data", "model"))
     return _make_mesh((data, model), ("data", "model"))
-
-
-def mesh_context(mesh):
-    """Version-portable ``with mesh:`` — ``jax.sharding.set_mesh`` where it
-    exists, the Mesh context manager on older releases."""
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
 
 
 def make_scoring_mesh(num_shards: int | None = None):

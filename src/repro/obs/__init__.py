@@ -68,6 +68,6 @@ from .export import MetricsExporter, prometheus_text  # noqa: F401
 from .forensics import ForensicsRecorder  # noqa: F401
 from .health import ALERT_KINDS, Alert, HealthMonitor  # noqa: F401
 from .metrics import Counter, Gauge, Histogram, MetricsRegistry  # noqa: F401
-from .profile import capture, profiler_available  # noqa: F401
+from .profile import capture  # noqa: F401
 from .report import aggregate_spans, write_report  # noqa: F401
 from .trace import NULL_TRACER, Tracer  # noqa: F401

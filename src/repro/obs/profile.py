@@ -10,8 +10,8 @@ the weak-scaling gap into named causes:
 * :func:`capture` — a ``jax.profiler`` capture-window context manager
   around any region; the resulting TensorBoard/Perfetto trace carries the
   ``jax.named_scope`` phase annotations the scoring programs already emit
-  (``gp_readout`` / ``score_topk`` / ``all_gather``).  Degrades to a no-op
-  when the profiler (or jax) is unavailable, so call sites never gate.
+  (``gp_readout`` / ``score_topk`` / ``all_gather``).  A capture that was
+  asked for and cannot start raises: it never degrades to an untraced run.
 * :func:`per_shard_skew` — runs one caller-built thunk pinned to each
   device of a scoring mesh (single-device sub-meshes) and reports the
   per-device timing spread.  On forced host-platform devices the "devices"
@@ -35,37 +35,22 @@ import time as _time
 PROFILE_SCHEMA_VERSION = 1
 
 
-def profiler_available() -> bool:
-    """True when ``jax.profiler`` trace capture is importable."""
-    try:
-        from jax import profiler  # noqa: F401
-        return hasattr(profiler, "start_trace")
-    except Exception:
-        return False
-
-
 @contextlib.contextmanager
 def capture(logdir: str | None = None):
     """``jax.profiler`` capture window: everything inside the ``with``
     lands in a TensorBoard/Perfetto trace under ``logdir``.  Yields True
-    when a capture is actually running, False when ``logdir`` is None or
-    the profiler is unavailable — callers need no gating of their own."""
+    while a capture runs, False when ``logdir`` is None.  When a
+    ``logdir`` was given and ``start_trace`` fails, the error propagates:
+    a measurement that asked for a trace must not run without one."""
     if logdir is None:
         yield False
         return
-    try:
-        from jax import profiler
-        profiler.start_trace(str(logdir))
-    except Exception:
-        yield False
-        return
+    from jax import profiler
+    profiler.start_trace(str(logdir))
     try:
         yield True
     finally:
-        try:
-            profiler.stop_trace()
-        except Exception:
-            pass
+        profiler.stop_trace()
 
 
 def time_us_blocked(fn, *, iters: int = 10, warmup: int = 2) -> float:
@@ -123,20 +108,18 @@ def dispatch_overhead_us(mesh, *, iters: int = 50, warmup: int = 5) -> float:
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro.shardgp.score import _NO_REP_CHECK, shard_map
-
     @jax.jit
     def trivial(x):
         def local(x):
             return x + 1.0
-        return shard_map(local, mesh=mesh, in_specs=(P("shard"),),
-                         out_specs=P("shard"), **_NO_REP_CHECK)(x)
+        return jax.shard_map(local, mesh=mesh, in_specs=(P("shard"),),
+                             out_specs=P("shard"), check_vma=False)(x)
 
     x = jax.device_put(jnp.zeros(mesh.devices.size, jnp.float32),
                        NamedSharding(mesh, P("shard")))
     return time_us_blocked(lambda: trivial(x), iters=iters, warmup=warmup)
 
 
-__all__ = ["capture", "profiler_available", "time_us_blocked",
+__all__ = ["capture", "time_us_blocked",
            "single_device_mesh", "per_shard_skew", "dispatch_overhead_us",
            "PROFILE_SCHEMA_VERSION"]

@@ -30,7 +30,6 @@ program recompiles only when capacity doubles.
 from __future__ import annotations
 
 import functools
-import inspect
 
 import jax
 import jax.numpy as jnp
@@ -40,20 +39,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core.ei import NEG_INF, ei_total
 from repro.obs import NULL_TRACER
 from repro.sharding.rules import SCORING_RULES
-
-# shard_map moved from jax.experimental to the jax namespace (and its
-# replication-check kwarg was renamed) across releases; resolve both here so
-# the pinned container jax and current releases run the same code.
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map
-_SM_PARAMS = inspect.signature(shard_map).parameters
-if "check_rep" in _SM_PARAMS:
-    _NO_REP_CHECK = {"check_rep": False}
-elif "check_vma" in _SM_PARAMS:  # pragma: no cover
-    _NO_REP_CHECK = {"check_vma": False}
-else:  # pragma: no cover
-    _NO_REP_CHECK = {}
 
 SCORE_KERNELS = ("xla", "pallas", "pallas_topk")
 
@@ -119,12 +104,12 @@ def _decide(mu, sd, best, member, cost, selected, speed, *, mesh, kernel, k):
             allv = jax.lax.all_gather(v, "shard").reshape(-1)
             allg = jax.lax.all_gather(g, "shard").reshape(-1)
         return allv, allg
-    allv, allg = shard_map(
+    allv, allg = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P_MODELS, P_MODELS, P_TENANTS, P_MEMBER,
                   P_MODELS, P_MODELS, P()),
         out_specs=(P(None), P(None)),
-        **_NO_REP_CHECK,
+        check_vma=False,
     )(mu, sd, best, member, cost, selected, speed)
     with jax.named_scope("global_pick"):
         return _global_pick(allv, allg, k)
@@ -160,12 +145,12 @@ def _decide_classes(mu, sd, best, member, cost, selected, rates, overheads,
         allg = jax.lax.all_gather(g, "shard")
         return allv, allg
 
-    allv, allg = shard_map(
+    allv, allg = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P_MODELS, P_MODELS, P_TENANTS, P_MEMBER,
                   P_MODELS, P_MODELS, P(), P()),
         out_specs=(P(None), P(None)),
-        **_NO_REP_CHECK,
+        check_vma=False,
     )(mu, sd, best, member, cost, selected, rates, overheads)
     # (S, C, k) -> (C, S*k): per class the flat order stays (shard, rank)-
     # major = ascending global id at equal value, so top_k's keep-earlier
@@ -197,12 +182,12 @@ def _readout_decide(W, alpha, mu0, kdiag, best, member, cost, selected, speed,
             allg = jax.lax.all_gather(g, "shard").reshape(-1)
         return allv, allg
 
-    allv, allg = shard_map(
+    allv, allg = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P_W, P_OBS, P_MODELS, P_MODELS, P_TENANTS,
                   P_MEMBER, P_MODELS, P_MODELS, P()),
         out_specs=(P(None), P(None)),
-        **_NO_REP_CHECK,
+        check_vma=False,
     )(W, alpha, mu0, kdiag, best, member, cost, selected, speed)
     with jax.named_scope("global_pick"):
         return _global_pick(allv, allg, k)
@@ -226,11 +211,11 @@ def _readout_phase(W, alpha, mu0, kdiag, *, mesh, kernel):
             return ops.gp_readout(W, alpha, mu0, kdiag, emit_sd=True,
                                   use_pallas=use_pallas)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P_W, P_OBS, P_MODELS, P_MODELS),
         out_specs=(P_MODELS, P_MODELS),
-        **_NO_REP_CHECK,
+        check_vma=False,
     )(W, alpha, mu0, kdiag)
 
 
@@ -245,12 +230,12 @@ def _local_candidates(mu, sd, best, member, cost, selected, speed,
             return _score_local(mu, sd, best, member, cost, selected, speed,
                                 kernel, k)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P_MODELS, P_MODELS, P_TENANTS, P_MEMBER,
                   P_MODELS, P_MODELS, P()),
         out_specs=(P_MODELS, P_MODELS),
-        **_NO_REP_CHECK,
+        check_vma=False,
     )(mu, sd, best, member, cost, selected, speed)
 
 
@@ -267,11 +252,11 @@ def _gather_pick(allv, allg, *, mesh, k):
             vv, pos = jax.lax.top_k(av, k)
             return vv, ag[pos]
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P_MODELS, P_MODELS),
         out_specs=(P(None), P(None)),
-        **_NO_REP_CHECK,
+        check_vma=False,
     )(allv, allg)
 
 

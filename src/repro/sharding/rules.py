@@ -209,19 +209,6 @@ def shape_dtype_for_tree(tree):
     )
 
 
-def _current_mesh():
-    """The ambient mesh, across jax versions: ``get_abstract_mesh`` where it
-    exists, the thread-resource physical mesh (the ``with mesh:`` context)
-    on older releases.  None when no mesh is active."""
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        mesh = get_abstract()
-        return None if mesh is None or mesh.empty else mesh
-    from jax.interpreters import pxla
-    mesh = pxla.thread_resources.env.physical_mesh
-    return None if mesh.empty else mesh
-
-
 def with_logical_constraint(x, logical_axes: tuple[str | None, ...], rules: AxisRules | None):
     """Annotate an activation with a logical sharding constraint.
 
@@ -230,8 +217,8 @@ def with_logical_constraint(x, logical_axes: tuple[str | None, ...], rules: Axis
     """
     if rules is None:
         return x
-    mesh = _current_mesh()
-    if mesh is None:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     spec = rules.mesh_axes(logical_axes)
     spec = _sanitize_pspec(spec, x.shape, mesh)

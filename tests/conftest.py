@@ -1,4 +1,5 @@
 import json
+import os
 import subprocess
 import sys
 import textwrap
@@ -32,7 +33,8 @@ def run_forced_devices_subprocess(code: str, devices: int = 8) -> dict:
     prog = textwrap.dedent(code)
     out = subprocess.run(
         [sys.executable, "-c", prog],
-        env={"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin", "HOME": "/root",
+        env={"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin",
+             "HOME": os.environ.get("HOME", ""),
              "JAX_PLATFORMS": "cpu",
              "XLA_FLAGS":
                  f"--xla_force_host_platform_device_count={devices}"},

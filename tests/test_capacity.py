@@ -362,6 +362,45 @@ def test_prometheus_renders_alert_counts_and_capacity_gauges():
     assert "alerts" not in bare.records[0]
 
 
+# ---- device failures are loud (obs/profile.py, benchmarks/common.py) ---------
+
+def test_capture_without_logdir_is_a_noop():
+    from repro.obs import capture
+    with capture(None) as running:
+        assert running is False
+
+
+def test_capture_raises_when_the_trace_cannot_start(monkeypatch, tmp_path):
+    import jax.profiler
+    from repro.obs import capture
+
+    def refuse(logdir):
+        raise RuntimeError("profiler unavailable")
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    with pytest.raises(RuntimeError, match="profiler unavailable"):
+        with capture(tmp_path):
+            pass
+
+
+def test_environment_stamp_names_the_device():
+    import jax
+    from benchmarks.common import environment
+    env = environment()
+    assert env["device_kind"] == jax.devices()[0].device_kind
+    assert env["device_count"] == len(jax.devices())
+
+
+def test_environment_stamp_raises_without_a_device(monkeypatch):
+    import jax
+    from benchmarks.common import environment
+
+    def no_backend(*a, **k):
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+    monkeypatch.setattr(jax, "devices", no_backend)
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+        environment()
+
+
 # ---- perf-regression plane (benchmarks/regress.py) ---------------------------
 
 from benchmarks import regress  # noqa: E402  (needs repo root on sys.path)

@@ -39,7 +39,7 @@ def test_train_step_runs_on_2x4_mesh():
         import json
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_smoke_config
-        from repro.launch.mesh import make_test_mesh, mesh_context
+        from repro.launch.mesh import make_test_mesh
         from repro.launch.specs import build_cell
         from repro.models.layers import init_from_specs
         from repro.sharding.rules import DEFAULT_RULES
@@ -66,7 +66,7 @@ def test_train_step_runs_on_2x4_mesh():
         from repro.train.train_step import train_state_specs
         st_sh = shardings_for_tree(train_state_specs(cfg, opt_cfg), mesh, DEFAULT_RULES)
         state = jax.device_put(state, st_sh)
-        with mesh_context(mesh):
+        with jax.sharding.set_mesh(mesh):
             step = jax.jit(fn, in_shardings=(st_sh, None), out_shardings=(st_sh, None))
             state2, metrics = step(state, batch)
         wq = state2.params["blocks"]["attn"]["wq"]
@@ -90,7 +90,7 @@ def test_dryrun_cell_on_small_mesh_has_collectives():
         import json
         import jax
         from repro.configs import get_smoke_config
-        from repro.launch.mesh import make_test_mesh, mesh_context
+        from repro.launch.mesh import make_test_mesh
         from repro.launch.specs import build_cell
         from repro.launch.hlo_analysis import parse_collectives
         from repro.sharding.rules import DEFAULT_RULES
@@ -98,7 +98,7 @@ def test_dryrun_cell_on_small_mesh_has_collectives():
         mesh = make_test_mesh(data=2, model=4)
         cfg = get_smoke_config("qwen3-4b")
         cell = build_cell(cfg, "train_4k", mesh, DEFAULT_RULES)
-        with mesh_context(mesh):
+        with jax.sharding.set_mesh(mesh):
             compiled = jax.jit(cell.fn, in_shardings=cell.in_shardings,
                                out_shardings=cell.out_shardings,
                                donate_argnums=cell.donate_argnums

@@ -68,6 +68,20 @@ def test_heterogeneous_device_speeds(problem):
     assert per_dev[1] > per_dev[0]
 
 
+def test_matches_event_engine_at_fig5_shape():
+    """The paper's Fig-5 shape (50 tenants x 50 candidates) in a batch of
+    several episodes: deep enough that the incremental Cholesky amplifies
+    any summation-order difference between the jitted fold and its
+    vmapped twin into a flipped decision (the engines split at trial 450
+    before the folds fixed their order)."""
+    problem = synthetic_matern_problem(num_users=50, num_models_per_user=50,
+                                       seed=0)
+    res = simulate(problem, "mdmt", num_devices=4, seed=0)
+    batch = simulate_batch(problem, [EpisodeSpec("mdmt", M, 0)
+                                     for M in (1, 4, 16)])
+    assert batched_sequence(batch, 1) == event_sequence(res)
+
+
 def test_vmap_batch_matches_singleton_runs(problem):
     """vmap over episodes == python loop of single-episode batches."""
     specs = [
