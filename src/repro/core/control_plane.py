@@ -60,13 +60,18 @@ from .ei import (
 )
 from .gp import DEFAULT_JITTER, BlockIncrementalGP, make_gp
 from .tenancy import Problem
-from repro.obs import NULL_TRACER
+from repro.obs.trace import NULL_TRACER, SCALAR_BYTES
 
 SCORERS = ("fused", "ops", "sharded")
 
 #: candidates kept per forensics record on the fused/ops paths (the
 #: sharded path keeps its scorer's own top-k)
 FORENSICS_TOPK = 4
+
+#: host scalars one GP fold uploads: the model index (for its kernel row
+#: and prior mean, and as an argument), the observed value and the
+#: observation count
+FOLD_SCALARS = 5
 
 _FLOOR_SDS = 5.0  # "no observation yet" sits this many prior sds below mu0
 
@@ -255,14 +260,22 @@ class ControlPlane:
     def _rebuild_mirrors(self) -> None:
         """Full host->device refresh; called at construction and on churn
         events (rare relative to decisions, which update incrementally)."""
-        self._membership_j = jnp.asarray(self.membership)
-        self._cost_j = jnp.asarray(self.cost.astype(np.float32))
-        self._selected_j = jnp.asarray(self.selected)
-        self._best_j = jnp.asarray(
-            np.where(np.isfinite(self.best), self.best,
-                     self._no_obs_floor).astype(np.float32))
-        if self._sharded is not None:
-            self._sharded.refresh(self.membership, self.cost)
+        tr = self.tracer
+        with tr.span("mirrors"):
+            cost = self.cost.astype(np.float32)
+            best = np.where(np.isfinite(self.best), self.best,
+                            self._no_obs_floor).astype(np.float32)
+            self._membership_j = jnp.asarray(self.membership)
+            self._cost_j = jnp.asarray(cost)
+            self._selected_j = jnp.asarray(self.selected)
+            self._best_j = jnp.asarray(best)
+            if self._sharded is not None:
+                self._sharded.refresh(self.membership, self.cost)
+            if tr.enabled:
+                tr.count("h2d_bytes", self.membership.nbytes + cost.nbytes
+                         + self.selected.nbytes + best.nbytes)
+                tr.sync((self._membership_j, self._cost_j, self._selected_j,
+                         self._best_j))
 
     def _grow(self, need_models: int, need_tenants: int) -> None:
         cap_n, cap_N = self.capacity, self.membership.shape[0]
@@ -317,26 +330,34 @@ class ControlPlane:
             raise ValueError("block shapes disagree")
         if (cost_block <= 0).any():
             raise ValueError("costs must be positive")
-        tid = (heappop(self._free_tenant_slots) if self._free_tenant_slots
-               else self._num_tenants)
-        start = self._layout.place(tid, m)
-        self._grow(self._layout.capacity, tid + 1)
-        self._num_tenants = max(self._num_tenants, tid + 1)
-        self._num_models += m
-        ids = np.arange(start, start + m, dtype=np.int64)
-        self._block_ids[tid] = self.gp.add_block(ids, K_block, mu0_block)
-        self.selected[ids] = False
-        self.observed[ids] = False
-        self.cost[ids] = cost_block
-        self.model_live[ids] = True
-        self.membership[tid, ids] = True
-        self.best[tid] = -np.inf
-        self.tenant_live[tid] = True
-        self._tenant_floor_stats[tid] = (
-            float(mu0_block.min()),
-            float(np.sqrt(np.clip(np.diag(K_block), 0, None).max())))
-        self._recompute_floor()
-        self._rebuild_mirrors()
+        tr = self.tracer
+        with tr.span("admit", models=m):
+            tid = (heappop(self._free_tenant_slots) if self._free_tenant_slots
+                   else self._num_tenants)
+            start = self._layout.place(tid, m)
+            self._grow(self._layout.capacity, tid + 1)
+            self._num_tenants = max(self._num_tenants, tid + 1)
+            self._num_models += m
+            ids = np.arange(start, start + m, dtype=np.int64)
+            self._block_ids[tid] = self.gp.add_block(ids, K_block, mu0_block)
+            self.selected[ids] = False
+            self.observed[ids] = False
+            self.cost[ids] = cost_block
+            self.model_live[ids] = True
+            self.membership[tid, ids] = True
+            self.best[tid] = -np.inf
+            self.tenant_live[tid] = True
+            self._tenant_floor_stats[tid] = (
+                float(mu0_block.min()),
+                float(np.sqrt(np.clip(np.diag(K_block), 0, None).max())))
+            self._recompute_floor()
+            self._rebuild_mirrors()
+            if tr.enabled:
+                # the prior block (kernel, mean, jitter) uploaded at admission
+                eng = self.gp._engines[self._block_ids[tid]]
+                tr.count("h2d_bytes",
+                         eng.K.nbytes + eng.mu0.nbytes + eng.jitter.nbytes)
+                tr.sync((eng.K, eng.mu0))
         return TenantHandle(tenant_id=tid, models=ids)
 
     def retire_tenant(self, tenant_id: int) -> None:
@@ -350,21 +371,22 @@ class ControlPlane:
                                "ControlPlanes (not from_problem)")
         if not self.tenant_live[tenant_id]:
             raise ValueError(f"tenant {tenant_id} is not live")
-        ids = np.nonzero(self.membership[tenant_id])[0]
-        self.gp.retire_block(self._block_ids.pop(tenant_id))
-        self.membership[tenant_id, :] = False
-        self.selected[ids] = True
-        self.observed[ids] = False
-        self.cost[ids] = 1.0
-        self.model_live[ids] = False
-        self.tenant_live[tenant_id] = False
-        self.best[tenant_id] = -np.inf
-        del self._tenant_floor_stats[tenant_id]
-        self._layout.release(tenant_id)
-        heappush(self._free_tenant_slots, tenant_id)
-        self._num_models -= len(ids)
-        self._recompute_floor()
-        self._rebuild_mirrors()
+        with self.tracer.span("retire"):
+            ids = np.nonzero(self.membership[tenant_id])[0]
+            self.gp.retire_block(self._block_ids.pop(tenant_id))
+            self.membership[tenant_id, :] = False
+            self.selected[ids] = True
+            self.observed[ids] = False
+            self.cost[ids] = 1.0
+            self.model_live[ids] = False
+            self.tenant_live[tenant_id] = False
+            self.best[tenant_id] = -np.inf
+            del self._tenant_floor_stats[tenant_id]
+            self._layout.release(tenant_id)
+            heappush(self._free_tenant_slots, tenant_id)
+            self._num_models -= len(ids)
+            self._recompute_floor()
+            self._rebuild_mirrors()
 
     def in_flight_mask(self) -> np.ndarray:
         """Models launched but not yet observed (their global ids are baked
@@ -706,15 +728,21 @@ class ControlPlane:
     def best_effective(self) -> np.ndarray:
         return np.where(np.isfinite(self.best), self.best, self._no_obs_floor)
 
+    def _count_scalar_upload(self, scalars: int) -> None:
+        if self.tracer.enabled:
+            self.tracer.count("h2d_bytes", scalars * SCALAR_BYTES)
+
     def record_start(self, model: int) -> None:
         self.selected[model] = True
         self._selected_j = self._selected_j.at[model].set(True)
+        self._count_scalar_upload(2)        # the index and the flag
 
     def record_failure(self, model: int) -> None:
         # Paper's abstraction makes failure handling trivial: the model was
         # never observed, so it simply returns to L \ L(t).
         self.selected[model] = False
         self._selected_j = self._selected_j.at[model].set(False)
+        self._count_scalar_upload(2)
 
     def record_observation(self, model: int, z: float) -> bool:
         """Fold one observation; returns True when it improved at least one
@@ -731,33 +759,61 @@ class ControlPlane:
                              f"{model}; poisoned losses must not reach the "
                              f"GP (use record_failure)")
         self.observed[model] = True
-        with self.tracer.span("gp_fold", model=model):
+        tr = self.tracer
+        with tr.span("gp_fold", model=model):
             self.gp.observe(model, z)
+            if tr.enabled:
+                tr.count("h2d_bytes", FOLD_SCALARS * SCALAR_BYTES)
+                tr.sync(self.gp.fold_outputs(model))
         users = np.nonzero(self.membership[:, model])[0]
         improved = False
         for u in users:
             if z > self.best[u] or not np.isfinite(self.best[u]):
                 self.best[u] = max(z, self.best[u]) if np.isfinite(self.best[u]) else z
                 self._best_j = self._best_j.at[u].set(self.best[u])
+                self._count_scalar_upload(2)    # the index and the value
                 improved = True
         return improved
 
     # ---- policy decisions --------------------------------------------------
+
+    def _posterior_sd(self, *, host: bool):
+        """The pool's posterior (mu, sd) under the ``posterior`` span: the
+        block engine's flush (``gp_flush``), then on the device path the
+        upload of its host cache and the sqrt (``posterior_upload``); with
+        ``host`` the cache itself and a host sqrt, for the sharded upload.
+        The dense engine reads out on the device."""
+        tr = self.tracer
+        with tr.span("posterior", scorer=self.scorer):
+            gp = self.gp
+            if not isinstance(gp, BlockIncrementalGP):
+                return tr.sync(gp.posterior_sd())
+            gp.flush(tr)
+            if host:
+                # float32 sqrt is bit-deterministic, so this matches the
+                # fused path's jnp sqrt exactly
+                mu, var = gp.posterior_host()
+                return mu, np.sqrt(var)
+            with tr.span("posterior_upload"):
+                if tr.enabled:
+                    tr.count("h2d_bytes", gp.readout_nbytes)
+                return tr.sync(gp.posterior_sd())
+
+    def _count_readback(self, *arrays) -> None:
+        """Count blocking readbacks of device ``arrays`` (those the host
+        converts), by their bytes."""
+        tr = self.tracer
+        if tr.enabled:
+            tr.count("host_syncs", len(arrays))
+            tr.count("d2h_bytes", sum(a.nbytes for a in arrays))
 
     def choose_mdmt(self, device_speed: float = 1.0) -> tuple[int, int] | None:
         if self.selected.all():
             return None
         tr = self.tracer
         if self.scorer == "sharded":
-            # stay on host buffers until the sharded upload: the block
-            # engine's cache is numpy, and float32 sqrt is bit-deterministic,
-            # so this matches the fused path's jnp sqrt exactly
-            with tr.span("posterior", scorer="sharded"):
-                if hasattr(self.gp, "posterior_host"):
-                    mu, var = self.gp.posterior_host()
-                    sd = np.sqrt(var)
-                else:
-                    mu, sd = tr.sync(self.gp.posterior_sd())
+            # stay on host buffers until the sharded upload
+            mu, sd = self._posterior_sd(host=True)
             with tr.span("score", scorer="sharded"):
                 if self._forensics is None:
                     idx, score = self._sharded.decide(
@@ -773,9 +829,12 @@ class ControlPlane:
             if not np.isfinite(score) or score <= -1e29:
                 return None
             return idx, -1
-        with tr.span("posterior", scorer=self.scorer):
-            mu, sd = tr.sync(self.gp.posterior_sd())
-        cost = self._cost_j if device_speed == 1.0 else self._cost_j / device_speed
+        mu, sd = self._posterior_sd(host=False)
+        if device_speed == 1.0:
+            cost = self._cost_j
+        else:
+            cost = self._cost_j / device_speed
+            self._count_scalar_upload(1)
         with tr.span("score", scorer=self.scorer):
             if self.scorer == "ops":
                 from repro.kernels import ops
@@ -784,11 +843,17 @@ class ControlPlane:
                     self._selected_j,
                     use_pallas=jax.default_backend() == "tpu")
                 idx = jnp.argmax(scores)
-                idx, score = int(idx), float(scores[idx])
+                self._count_readback(idx)
+                idx = int(idx)
+                score = scores[idx]
+                self._count_scalar_upload(1)    # the index into scores
+                self._count_readback(score)
+                score = float(score)
             else:
                 idx, score = choose_next_fused(
                     mu, sd, self._best_j, self._membership_j, cost,
                     self._selected_j)
+                self._count_readback(idx, score)
                 idx, score = int(idx), float(score)
         if self._forensics is not None:
             # one additional jitted top-k over the same masked EIrate
@@ -820,29 +885,26 @@ class ControlPlane:
         """
         rates_j = jnp.asarray(np.asarray(rates, np.float32))
         over_j = jnp.asarray(np.asarray(overheads, np.float32))
+        tr = self.tracer
+        if tr.enabled:
+            tr.count("h2d_bytes", rates_j.nbytes + over_j.nbytes)
         if self.selected.all():
             # same early-out as choose_mdmt: an empty pool must not pay a
             # scoring pass (dry passes dominate idle stretches)
             C = rates_j.shape[0]
             return (np.full((C, k), -np.inf, np.float32),
                     np.zeros((C, k), np.int64))
-        tr = self.tracer
         if self.scorer == "sharded":
-            with tr.span("posterior", scorer="sharded"):
-                if hasattr(self.gp, "posterior_host"):
-                    mu, var = self.gp.posterior_host()
-                    sd = np.sqrt(var)
-                else:
-                    mu, sd = tr.sync(self.gp.posterior_sd())
+            mu, sd = self._posterior_sd(host=True)
             with tr.span("score_topk", scorer="sharded", k=k):
                 v, g = self._sharded.decide_topk_classes(
                     mu, sd, self._best_j, self.selected, rates_j, over_j, k=k)
+                self._count_readback(v, g)
                 v, g = np.asarray(v), np.asarray(g)
                 self._record_batch_forensics(v, g, mu, sd, rates, overheads,
                                              class_names)
                 return v, g
-        with tr.span("posterior", scorer=self.scorer):
-            mu, sd = tr.sync(self.gp.posterior_sd())
+        mu, sd = self._posterior_sd(host=False)
         cm = self._cost_j[None, :] / rates_j[:, None] + over_j[:, None]
         with tr.span("score_topk", scorer=self.scorer, k=k):
             if self.scorer == "ops":
@@ -856,6 +918,7 @@ class ControlPlane:
                 v, i = choose_topk_classes(
                     mu, sd, self._best_j, self._membership_j, cm,
                     self._selected_j, k=k)
+            self._count_readback(v, i)
             v, i = np.asarray(v), np.asarray(i)
             self._record_batch_forensics(v, i, mu, sd, rates, overheads,
                                          class_names)

@@ -27,6 +27,8 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from repro.obs.trace import NULL_TRACER, SCALAR_BYTES
+
 
 DEFAULT_JITTER = 1e-6
 
@@ -187,6 +189,11 @@ class IncrementalGP:
     @property
     def num_observed(self) -> int:
         return self._k
+
+    def fold_outputs(self, idx: int) -> tuple:
+        """The buffers the most recent fold wrote (a timed fold waits on
+        them); this engine holds every model, so ``idx`` picks nothing."""
+        return self._W, self._alpha, self._diag_acc
 
     def resource_stats(self) -> dict:
         """Analytic byte/observation accounting of this engine's buffers
@@ -381,6 +388,16 @@ class BlockIncrementalGP:
     def num_observed(self) -> int:
         return len(self.observed)
 
+    def fold_outputs(self, idx: int) -> tuple:
+        """The buffers the most recent fold of model ``idx``'s block wrote."""
+        return self._engines[self._local[idx][0]].fold_outputs(idx)
+
+    @property
+    def readout_nbytes(self) -> int:
+        """Bytes of the host readout cache (``_mu`` + ``_var``, float32 over
+        the full capacity): what one posterior upload moves."""
+        return self._mu.nbytes + self._var.nbytes
+
     def resource_stats(self) -> dict:
         """Per-block + aggregate resource accounting (obs/accounting.py).
 
@@ -391,7 +408,6 @@ class BlockIncrementalGP:
         plane's disabled-path cost discipline holds."""
         blocks = {bid: eng.resource_stats()
                   for bid, eng in sorted(self._engines.items())}
-        readout = 2 * self.n * 4          # _mu + _var, float32 each
         return {
             "blocks": blocks,
             "num_blocks": len(blocks),
@@ -399,20 +415,29 @@ class BlockIncrementalGP:
             "obs_total": sum(b["obs"] for b in blocks.values()),
             "alloc_bytes": sum(b["alloc_bytes"] for b in blocks.values()),
             "active_bytes": sum(b["active_bytes"] for b in blocks.values()),
-            "readout_bytes": readout,
+            "readout_bytes": self.readout_nbytes,
         }
 
-    def _flush(self) -> None:
+    def flush(self, tracer=NULL_TRACER) -> None:
+        """Read the dirty blocks' posteriors back into the host readout
+        cache, under a ``gp_flush`` span (attr ``blocks``).  Each block's
+        readback is where the host first waits for its folds."""
         import numpy as np
-        for bi in self._dirty:
-            mu_b, var_b = self._engines[bi].posterior()
-            b = self._blocks[bi]
-            self._mu[b] = np.asarray(mu_b)
-            self._var[b] = np.asarray(var_b)
-        self._dirty.clear()
+        with tracer.span("gp_flush", blocks=len(self._dirty)):
+            for bi in self._dirty:
+                mu_b, var_b = self._engines[bi].posterior()
+                b = self._blocks[bi]
+                mu_h, var_h = np.asarray(mu_b), np.asarray(var_b)
+                self._mu[b] = mu_h
+                self._var[b] = var_h
+                if tracer.enabled:
+                    tracer.count("host_syncs", 2)
+                    tracer.count("h2d_bytes", SCALAR_BYTES)  # readout's k
+                    tracer.count("d2h_bytes", mu_h.nbytes + var_h.nbytes)
+            self._dirty.clear()
 
     def posterior(self):
-        self._flush()
+        self.flush()
         return jnp.asarray(self._mu), jnp.asarray(self._var)
 
     def posterior_host(self):
@@ -420,7 +445,7 @@ class BlockIncrementalGP:
         convention — callers must not mutate).  The sharded scorer consumes
         these directly: wrapping them in device arrays here only to convert
         back before the sharded upload would round-trip every decision."""
-        self._flush()
+        self.flush()
         return self._mu, self._var
 
     def posterior_sd(self):

@@ -11,12 +11,14 @@ this package gives the control plane three observation planes (DESIGN.md
               sync so device work is attributed to the span that launched
               it, and an optional ``jax.profiler`` trace-annotation bridge
               (spans show up in TensorBoard/Perfetto device profiles).
-              Disabled tracers cost one branch per span site (<1% of a
-              decision, measured in BENCH_decision_trace.json).
+              Disabled tracers cost one branch per site; spans also
+              carry counts (host syncs, bytes moved each way).  The
+              cost, tracing off and on, measured on a TPU v5e, is in
+              ``trace.py``'s docstring.
 
   metrics.py  :class:`MetricsRegistry` — counters, gauges, and fixed-bucket
               histograms with p50/p99 snapshots.  The streaming engines feed
-              it (decisions, decision latency, queue depth, compaction
+              it (events, launches, decision latency, queue depth, compaction
               pause, snapshot latency, per-device busy fraction) and the
               snapshot exports through the existing telemetry JSON sink.
 

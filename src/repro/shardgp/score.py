@@ -37,7 +37,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.ei import NEG_INF, ei_total
-from repro.obs import NULL_TRACER
+from repro.obs.trace import NULL_TRACER, SCALAR_BYTES
 from repro.sharding.rules import SCORING_RULES
 
 SCORE_KERNELS = ("xla", "pallas", "pallas_topk")
@@ -308,6 +308,8 @@ class ShardedScorer:
             c, NamedSharding(self.mesh, P_MODELS))
         self._cost_host = c
         self._cap = cap
+        if self.tracer.enabled:
+            self.tracer.count("h2d_bytes", mem.nbytes + c.nbytes)
 
     def _pad(self, x, fill, dtype):
         x = np.asarray(x)
@@ -330,6 +332,9 @@ class ShardedScorer:
             sel = self._pad(np.asarray(selected), True, bool)
         with tr.span("shard_decide", shards=self.num_shards,
                      kernel=self.kernel):
+            if tr.enabled:      # the padded pool and the speed
+                tr.count("h2d_bytes", mu.nbytes + sd.nbytes + sel.nbytes
+                         + SCALAR_BYTES)
             return tr.sync(_decide(
                 mu, sd, jnp.asarray(best, dtype=jnp.float32), self._member,
                 self._cost, sel, jnp.float32(speed),
@@ -340,7 +345,11 @@ class ShardedScorer:
         """The decision the control plane consumes: global argmax (lowest-id
         tie-break) and its score."""
         v, g = self.decide_topk(mu, sd, best, selected, speed)
-        return int(g[0]), float(v[0])
+        idx, score = g[0], v[0]
+        if self.tracer.enabled:
+            self.tracer.count("host_syncs", 2)
+            self.tracer.count("d2h_bytes", idx.nbytes + score.nbytes)
+        return int(idx), float(score)
 
     def decide_topk_classes(self, mu, sd, best, selected, rates, overheads,
                             k: int | None = None):
@@ -359,6 +368,8 @@ class ShardedScorer:
             sel = self._pad(np.asarray(selected), True, bool)
         with tr.span("shard_decide", shards=self.num_shards,
                      kernel=self.kernel, k=k):
+            if tr.enabled:
+                tr.count("h2d_bytes", mu.nbytes + sd.nbytes + sel.nbytes)
             return tr.sync(_decide_classes(
                 mu, sd, jnp.asarray(best, dtype=jnp.float32), self._member,
                 self._cost, sel, jnp.asarray(rates, dtype=jnp.float32),

@@ -859,9 +859,6 @@ class StreamEngine:
 
         self.telemetry.on_end(self._t, self.fleet.num_devices)
         if self.metrics is not None:
-            if self._decision_seconds > 0:
-                self.metrics.gauge("engine.decisions_per_s").set(
-                    self._decisions / self._decision_seconds)
             for d, row in self.telemetry.per_device().items():
                 self.metrics.gauge(f"device.{d}.busy_fraction").set(
                     row["utilization"])

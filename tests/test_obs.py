@@ -23,9 +23,12 @@ import re
 import time
 from pathlib import Path
 
+import numpy as np
 import pytest
 
+from repro.core import ControlPlane
 from repro.core.fleet import Fleet
+from repro.core.tenancy import _matern_block_chol
 from repro.obs import (ALERT_KINDS, NULL_TRACER, ForensicsRecorder,
                        HealthMonitor, MetricsExporter, MetricsRegistry,
                        Tracer, aggregate_spans, prometheus_text,
@@ -35,6 +38,7 @@ from repro.obs.report import _slo_section
 from repro.obs.trace import ROOT_TRACE
 from repro.stream import (EventLog, FaultInjector, SimulatedCrash,
                           StreamEngine, poisson_churn_trace, recover)
+from repro.stream.workload import ChurnTrace, TenantArrive, TenantDepart
 
 
 # ---- tracer -----------------------------------------------------------------
@@ -291,13 +295,15 @@ def test_traced_run_matches_untraced_and_stamps_records():
 
     names = {r["name"] for r in tr.records()}
     assert {"event", "decide", "posterior", "score", "launch",
-            "gp_fold"} <= names
+            "gp_fold", "gp_flush", "posterior_upload", "admit", "retire",
+            "mirrors"} <= names
 
     snap = reg.snapshot()
     assert snap["counters"]["engine.events"] == eng.event_index
     assert snap["counters"]["engine.launches"] == len(res.trials)
     assert snap["histograms"]["engine.decision_seconds"]["count"] > 0
-    assert "engine.decisions_per_s" in snap["gauges"]
+    # no host-seconds "rate" under the benchmark's end-to-end metric's name
+    assert "engine.decisions_per_s" not in snap["gauges"]
     assert any(k.endswith(".busy_fraction") for k in snap["gauges"])
     json.dumps(snap, allow_nan=False)
 
@@ -334,6 +340,138 @@ def test_replayed_suffix_reemits_identical_span_tree(tmp_path):
     assert (crashed_tr.signature(min_trace=upto)[:20]
             == [s for s in ref_tr.signature(min_trace=upto)
                 if s[0] <= eng.event_index][:20])
+
+
+# ---- spans and counts inside the decision path -------------------------------
+
+def _block(m: int):
+    """(K, mu0, cost) of one tenant's m-point Matérn prior, costs rising."""
+    K, _ = _matern_block_chol(m, 0.3, 0.04)
+    return K, np.zeros(m), np.linspace(1.0, 2.0, m)
+
+
+def _one_tenant_trace(m: int = 4) -> ChurnTrace:
+    """One tenant on one slice: arrives at 0, every model runs by t = 7,
+    departs at 10."""
+    K, mu0, cost = _block(m)
+    z = np.linspace(0.1, 0.4, m)[::-1].copy()
+    return ChurnTrace((TenantArrive(0.0, 0, K, mu0, cost, z),
+                       TenantDepart(10.0, 0)))
+
+
+def _trees(records) -> dict:
+    """{event kind: [(span, parent, name, attrs) of each trace of it]}"""
+    traces: dict = {}
+    for r in sorted(records, key=lambda r: (r["trace"], r["span"])):
+        traces.setdefault(r["trace"], []).append(
+            (r["span"], r["parent"], r["name"], r["attrs"]))
+    out: dict = {}
+    for spans in traces.values():
+        out.setdefault(spans[0][3]["kind"], []).append(spans)
+    return out
+
+
+@pytest.mark.parametrize("kind, tree", [
+    # a completion: the fold, then a decision (flush and upload under the
+    # posterior) and its launch
+    ("finish", [(0, None, "event"), (1, 0, "gp_fold"), (2, 0, "decide"),
+                (3, 2, "posterior"), (4, 3, "gp_flush"),
+                (5, 3, "posterior_upload"), (6, 2, "score"),
+                (7, 0, "launch")]),
+    # an arrival: admission with its mirror rebuild, then the warm start
+    ("arrive", [(0, None, "event"), (1, 0, "admit"), (2, 1, "mirrors"),
+                (3, 0, "launch")]),
+    # a departure: retirement with its mirror rebuild; the freed slice's
+    # decision finds nothing left to launch
+    ("depart", [(0, None, "event"), (1, 0, "retire"), (2, 1, "mirrors"),
+                (3, 0, "decide")]),
+])
+def test_span_tree_of_each_event_kind(kind, tree):
+    tr = Tracer(enabled=True)
+    StreamEngine(Fleet.partition_pod(16, 1), "mdmt", seed=0, warm_start=1,
+                 tracer=tr).run(_one_tenant_trace())
+    first = _trees(tr.records())[kind][0]
+    assert [(s, p, n) for s, p, n, _ in first] == tree
+    attrs = {n: a for _, _, n, a in first}
+    assert attrs.get("admit", {"models": 4}) == {"models": 4}
+    assert attrs.get("gp_flush", {"blocks": 1}) == {"blocks": 1}
+
+
+def _sum_counts(records, trace_id) -> dict:
+    out: dict = {}
+    for r in records:
+        if r["trace"] == trace_id:
+            for k, v in r["counts"].items():
+                out[k] = out.get(k, 0) + v
+    return out
+
+
+@pytest.mark.parametrize("scorer", ["fused", "sharded"])
+def test_counts_of_a_decision_follow_from_its_shapes(scorer):
+    cp = ControlPlane(np.random.default_rng(0), scorer=scorer, num_shards=1)
+    first = cp.add_tenant(*_block(3))
+    tr = Tracer()
+    cp.set_tracer(tr)
+    m = 5
+    model = int(first.models[0])
+    steps = [lambda: cp.add_tenant(*_block(m)),
+             lambda: cp.record_start(model),
+             lambda: cp.record_observation(model, 0.1),
+             lambda: cp.choose_mdmt()]
+    for i, step in enumerate(steps):
+        tr.begin_trace(i)
+        with tr.span("step"):
+            out = step()
+    assert out is not None                  # the decision picked a model
+    cap, slots = cp.capacity, cp.membership.shape[0]
+    recs = tr.records()
+    f32, scalar = 4, 4
+    mirrors = slots * cap + cap * f32 + cap + slots * f32
+    if scorer == "sharded":                 # its own membership and costs
+        mirrors += slots * cap + cap * f32
+    assert _sum_counts(recs, 0) == {
+        "h2d_bytes": m * m * f32 + m * f32 + scalar + mirrors}
+    assert _sum_counts(recs, 1) == {"h2d_bytes": 2 * scalar}
+    # the fold's five scalars, and the first incumbent of tenant 0
+    assert _sum_counts(recs, 2) == {"h2d_bytes": 5 * scalar + 2 * scalar}
+    # the dirty 3-wide block read back (mean, variance) with its readout's
+    # observation count; the pool's upload (fused: the cached means and
+    # variances; sharded: means, sds and the selected mask, and the
+    # device speed); the pick read back (index, score)
+    pool = (2 * cap * f32 if scorer == "fused"
+            else cap * (2 * f32 + 1) + scalar)
+    assert _sum_counts(recs, 3) == {
+        "host_syncs": 2 + 2,
+        "d2h_bytes": 2 * 3 * f32 + 2 * scalar,
+        "h2d_bytes": scalar + pool}
+    assert tr.counts == {}
+
+
+def test_counts_land_on_the_innermost_open_span():
+    tr = Tracer()
+    tr.count("host_syncs", 1)
+    tr.begin_trace(0)
+    with tr.span("outer"):
+        tr.count("h2d_bytes", 8)
+        with tr.span("inner"):
+            tr.count("h2d_bytes", 4)
+            tr.count("h2d_bytes", 4)
+    by_name = {r["name"]: r for r in tr.records()}
+    assert by_name["inner"]["counts"] == {"h2d_bytes": 8}
+    assert by_name["outer"]["counts"] == {"h2d_bytes": 8}
+    assert tr.counts == {"host_syncs": 1}
+    # counts are not part of the replay signature
+    assert tr.signature() == [(0, 1, 0, "inner", ()), (0, 0, None, "outer", ())]
+
+
+def test_disabled_tracer_counts_nothing():
+    tr = Tracer(enabled=False)
+    tr.count("host_syncs", 3)
+    with tr.span("a"):
+        tr.count("h2d_bytes", 4)
+    assert tr.counts == {} and tr.records() == []
+    _factory()().run(_trace())
+    assert NULL_TRACER.counts == {} and NULL_TRACER.records() == []
 
 
 # ---- report plane -----------------------------------------------------------
