@@ -553,9 +553,9 @@ class ControlPlane:
         # exact cache bytes (incl. stale masked entries of retired blocks),
         # and the dirty set as of the snapshot — the next flush recomputes
         # exactly what the uninterrupted run would have
-        self.gp._mu = np.array(arrays["cp/gp_mu"], dtype=np.float32)
-        self.gp._var = np.array(arrays["cp/gp_var"], dtype=np.float32)
-        self.gp._dirty = {self._block_ids[t] for t in meta["gp_dirty"]}
+        self.gp.restore_cache(
+            arrays["cp/gp_mu"], arrays["cp/gp_var"],
+            [self._block_ids[t] for t in meta["gp_dirty"]])
         self._rebuild_mirrors()
 
     # ---- mesh shrink / regrow (DESIGN.md §16) ------------------------------
@@ -778,26 +778,26 @@ class ControlPlane:
     # ---- policy decisions --------------------------------------------------
 
     def _posterior_sd(self, *, host: bool):
-        """The pool's posterior (mu, sd) under the ``posterior`` span: the
-        block engine's flush (``gp_flush``), then on the device path the
-        upload of its host cache and the sqrt (``posterior_upload``); with
-        ``host`` the cache itself and a host sqrt, for the sharded upload.
-        The dense engine reads out on the device."""
+        """The pool's posterior (mu, sd) under the ``posterior`` span.  On
+        the device path, the block engine's device pool after its device
+        flush (``gp_flush``: a dispatch for each dirty block, and after a
+        layout change a ``posterior_upload`` first); with ``host``, its
+        host cache after the host flush (the dirty blocks read back) and a
+        host sqrt, for the sharded upload.  The dense engine reads out on
+        the device."""
         tr = self.tracer
         with tr.span("posterior", scorer=self.scorer):
             gp = self.gp
             if not isinstance(gp, BlockIncrementalGP):
                 return tr.sync(gp.posterior_sd())
-            gp.flush(tr)
             if host:
+                gp.flush(tr)
                 # float32 sqrt is bit-deterministic, so this matches the
-                # fused path's jnp sqrt exactly
+                # device pool's jnp sqrt exactly
                 mu, var = gp.posterior_host()
                 return mu, np.sqrt(var)
-            with tr.span("posterior_upload"):
-                if tr.enabled:
-                    tr.count("h2d_bytes", gp.readout_nbytes)
-                return tr.sync(gp.posterior_sd())
+            mu, _, sd = gp.flush_device(tr)
+            return tr.sync((mu, sd))
 
     def _count_readback(self, *arrays) -> None:
         """Count blocking readbacks of device ``arrays`` (those the host

@@ -136,6 +136,18 @@ def _readout(W, alpha, mu0, kdiag, diag_acc, k):
     return mu, var
 
 
+@jax.jit
+def _readout_into(mu_pool, var_pool, sd_pool, idx, W, alpha, mu0, kdiag,
+                  diag_acc, k):
+    """One block's readout (``_readout``'s own program, so the fold's row
+    order) scattered at its global indices ``idx`` into the pool's device
+    mean, variance and sd.  The pools are not donated: a caller may still
+    hold the previous decision's arrays."""
+    mu, var = _readout(W, alpha, mu0, kdiag, diag_acc, k)
+    return (mu_pool.at[idx].set(mu), var_pool.at[idx].set(var),
+            sd_pool.at[idx].set(jnp.sqrt(var)))
+
+
 class IncrementalGP:
     """Incremental zero-noise GP posterior over a fixed finite model set."""
 
@@ -213,12 +225,17 @@ class IncrementalGP:
             "dtype_bytes": item,
         }
 
-    def posterior(self) -> tuple[jax.Array, jax.Array]:
-        """(mu, var) over all n models, O(n^2) readout (jitted, row-major)."""
+    def readout_args(self) -> tuple:
+        """The arguments of ``_readout`` (and the tail of
+        ``_readout_into``'s): the buffers and the observation count."""
         if self._kdiag is None:
             self._kdiag = jnp.diag(self.K)
-        return _readout(self._W, self._alpha, self.mu0, self._kdiag,
-                        self._diag_acc, jnp.asarray(self._k))
+        return (self._W, self._alpha, self.mu0, self._kdiag, self._diag_acc,
+                jnp.asarray(self._k))
+
+    def posterior(self) -> tuple[jax.Array, jax.Array]:
+        """(mu, var) over all n models, O(n^2) readout (jitted, row-major)."""
+        return _readout(*self.readout_args())
 
     def posterior_sd(self) -> tuple[jax.Array, jax.Array]:
         mu, var = self.posterior()
@@ -242,6 +259,16 @@ class BlockIncrementalGP:
     at runtime without refactorizing any other tenant's state.  Retired
     entries keep their last posterior values in the cached readout; callers
     mask them (the streaming control plane marks them selected).
+
+    The readout is kept twice over the whole capacity, each brought up to
+    date by its own flush: a host cache (``_mu``/``_var``, :meth:`flush`;
+    the sharded scorer and checkpoints read it) and a device pool of mean,
+    variance and sd (:meth:`flush_device`; the device scorers read it),
+    which a fold updates in place with one dispatch and no readback.
+    ``_dirty`` and ``_dirty_dev`` name the blocks folded since each last
+    saw them.  A change of the host cache's layout (admission, relocation,
+    growth, a restore) drops the pool; the next device flush uploads the
+    host cache once and recomputes the blocks it lacks.
     """
 
     def __init__(self, K=None, mu0=None, blocks: list | None = None,
@@ -256,6 +283,9 @@ class BlockIncrementalGP:
         self._mu = np.zeros(0, np.float32)
         self._var = np.zeros(0, np.float32)
         self._dirty: set[int] = set()
+        self._pool = None       # device (mu, var, sd); None: upload anew
+        self._dirty_dev: set[int] = set()
+        self._idx_dev: dict[int, jax.Array] = {}    # block's global ids
         self.observed: list[int] = []
         self._z = {}
         self.last_d2 = None     # pivot d² of the most recent fold
@@ -289,6 +319,7 @@ class BlockIncrementalGP:
         self._mu = np.concatenate([self._mu, np.zeros(grow, np.float32)])
         self._var = np.concatenate([self._var, np.zeros(grow, np.float32)])
         self.n = n_cap
+        self._pool = None
 
     def add_block(self, indices, K_block, mu0_block) -> int:
         """Register one tenant's covariance block at the given global model
@@ -311,7 +342,7 @@ class BlockIncrementalGP:
             self._local[int(g)] = (bid, li)
         self._mu[b] = mu0_block.astype(np.float32)
         self._var[b] = np.clip(np.diag(K_block), 0, None).astype(np.float32)
-        self._dirty.discard(bid)
+        self._pool = None
         return bid
 
     def retire_block(self, block_id: int) -> None:
@@ -321,6 +352,8 @@ class BlockIncrementalGP:
         b = self._blocks.pop(block_id)
         self._engines.pop(block_id)
         self._dirty.discard(block_id)
+        self._dirty_dev.discard(block_id)
+        self._idx_dev.pop(block_id, None)
         for g in b.tolist():
             del self._local[int(g)]
 
@@ -350,6 +383,8 @@ class BlockIncrementalGP:
         self._mu[new] = mu_b
         self._var[new] = var_b
         self._blocks[block_id] = new
+        self._idx_dev.pop(block_id, None)
+        self._pool = None
 
     @staticmethod
     def blocks_from_membership(K, membership, atol: float = 0.0) -> list | None:
@@ -381,6 +416,7 @@ class BlockIncrementalGP:
         self._engines[bi].observe(li, z_val)
         self.last_d2 = self._engines[bi].last_d2
         self._dirty.add(bi)
+        self._dirty_dev.add(bi)
         self.observed.append(idx)
         self._z[idx] = float(z_val)
 
@@ -436,9 +472,53 @@ class BlockIncrementalGP:
                     tracer.count("d2h_bytes", mu_h.nbytes + var_h.nbytes)
             self._dirty.clear()
 
+    def restore_cache(self, mu, var, dirty) -> None:
+        """Overwrite the host cache and its dirty set (a checkpoint's);
+        the device pool is uploaded from them at the next device flush."""
+        import numpy as np
+        self._mu = np.array(mu, dtype=np.float32)
+        self._var = np.array(var, dtype=np.float32)
+        self._dirty = set(dirty)
+        self._dirty_dev.clear()
+        self._pool = None
+
+    def flush_device(self, tracer=NULL_TRACER) -> tuple:
+        """Bring the device pool up to date and return it as ``(mu, var,
+        sd)``: one ``_readout_into`` dispatch for each block folded since
+        the pool last saw it, under a ``gp_flush`` span (attr ``blocks``),
+        with nothing read back.  A dropped pool is first uploaded from the
+        host cache under ``posterior_upload`` (the sqrt on the device), and
+        counted as ``pool_uploads`` on the enclosing span."""
+        if self._pool is None:
+            with tracer.span("posterior_upload"):
+                mu, var = jnp.asarray(self._mu), jnp.asarray(self._var)
+                self._pool = (mu, var, jnp.sqrt(var))
+                # the blocks the host cache lacks
+                self._dirty_dev |= self._dirty
+                if tracer.enabled:
+                    tracer.count("h2d_bytes", self.readout_nbytes)
+                    tracer.sync(self._pool)
+            if tracer.enabled:
+                tracer.count("pool_uploads", 1)
+        with tracer.span("gp_flush", blocks=len(self._dirty_dev)):
+            for bi in self._dirty_dev:
+                idx = self._idx_dev.get(bi)
+                if idx is None:
+                    idx = jnp.asarray(self._blocks[bi], dtype=jnp.int32)
+                    self._idx_dev[bi] = idx
+                    if tracer.enabled:
+                        tracer.count("h2d_bytes", idx.nbytes)
+                self._pool = _readout_into(
+                    *self._pool, idx, *self._engines[bi].readout_args())
+                if tracer.enabled:
+                    tracer.count("h2d_bytes", SCALAR_BYTES)  # readout's k
+            self._dirty_dev.clear()
+        return self._pool
+
     def posterior(self):
-        self.flush()
-        return jnp.asarray(self._mu), jnp.asarray(self._var)
+        """(mu, var) of the device pool, brought up to date."""
+        mu, var, _ = self.flush_device()
+        return mu, var
 
     def posterior_host(self):
         """(mu, var) as the engine's own host numpy buffers (read-only by
@@ -449,8 +529,9 @@ class BlockIncrementalGP:
         return self._mu, self._var
 
     def posterior_sd(self):
-        mu, var = self.posterior()
-        return mu, jnp.sqrt(var)
+        """(mu, sd) of the device pool, brought up to date."""
+        mu, _, sd = self.flush_device()
+        return mu, sd
 
 
 def make_gp(K, mu0, membership=None, jitter: float = DEFAULT_JITTER):

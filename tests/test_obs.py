@@ -372,11 +372,12 @@ def _trees(records) -> dict:
 
 
 @pytest.mark.parametrize("kind, tree", [
-    # a completion: the fold, then a decision (flush and upload under the
-    # posterior) and its launch
+    # the first completion: the fold, then a decision (under the posterior
+    # the device pool's upload after the admission, then the flush) and
+    # its launch
     ("finish", [(0, None, "event"), (1, 0, "gp_fold"), (2, 0, "decide"),
-                (3, 2, "posterior"), (4, 3, "gp_flush"),
-                (5, 3, "posterior_upload"), (6, 2, "score"),
+                (3, 2, "posterior"), (4, 3, "posterior_upload"),
+                (5, 3, "gp_flush"), (6, 2, "score"),
                 (7, 0, "launch")]),
     # an arrival: admission with its mirror rebuild, then the warm start
     ("arrive", [(0, None, "event"), (1, 0, "admit"), (2, 1, "mirrors"),
@@ -397,6 +398,20 @@ def test_span_tree_of_each_event_kind(kind, tree):
     assert attrs.get("gp_flush", {"blocks": 1}) == {"blocks": 1}
 
 
+def test_span_tree_of_a_later_completion():
+    """The device pool outlives a decision: a later completion's flush
+    dispatches the dirty block's readout, with no upload."""
+    tr = Tracer(enabled=True)
+    StreamEngine(Fleet.partition_pod(16, 1), "mdmt", seed=0, warm_start=1,
+                 tracer=tr).run(_one_tenant_trace())
+    later = _trees(tr.records())["finish"][1]
+    assert [(s, p, n) for s, p, n, _ in later] == [
+        (0, None, "event"), (1, 0, "gp_fold"), (2, 0, "decide"),
+        (3, 2, "posterior"), (4, 3, "gp_flush"), (5, 2, "score"),
+        (6, 0, "launch")]
+    assert {n: a for _, _, n, a in later}["gp_flush"] == {"blocks": 1}
+
+
 def _sum_counts(records, trace_id) -> dict:
     out: dict = {}
     for r in records:
@@ -413,16 +428,19 @@ def test_counts_of_a_decision_follow_from_its_shapes(scorer):
     tr = Tracer()
     cp.set_tracer(tr)
     m = 5
-    model = int(first.models[0])
+    model, other = int(first.models[0]), int(first.models[1])
     steps = [lambda: cp.add_tenant(*_block(m)),
              lambda: cp.record_start(model),
              lambda: cp.record_observation(model, 0.1),
+             lambda: cp.choose_mdmt(),
+             lambda: cp.record_observation(other, 0.05),
              lambda: cp.choose_mdmt()]
+    picks = []
     for i, step in enumerate(steps):
         tr.begin_trace(i)
         with tr.span("step"):
-            out = step()
-    assert out is not None                  # the decision picked a model
+            picks.append(step())
+    assert picks[3] is not None and picks[5] is not None
     cap, slots = cp.capacity, cp.membership.shape[0]
     recs = tr.records()
     f32, scalar = 4, 4
@@ -434,17 +452,64 @@ def test_counts_of_a_decision_follow_from_its_shapes(scorer):
     assert _sum_counts(recs, 1) == {"h2d_bytes": 2 * scalar}
     # the fold's five scalars, and the first incumbent of tenant 0
     assert _sum_counts(recs, 2) == {"h2d_bytes": 5 * scalar + 2 * scalar}
-    # the dirty 3-wide block read back (mean, variance) with its readout's
-    # observation count; the pool's upload (fused: the cached means and
-    # variances; sharded: means, sds and the selected mask, and the
-    # device speed); the pick read back (index, score)
-    pool = (2 * cap * f32 if scorer == "fused"
-            else cap * (2 * f32 + 1) + scalar)
-    assert _sum_counts(recs, 3) == {
-        "host_syncs": 2 + 2,
-        "d2h_bytes": 2 * 3 * f32 + 2 * scalar,
-        "h2d_bytes": scalar + pool}
+    # a fold that improves no incumbent: its five scalars alone
+    assert _sum_counts(recs, 4) == {"h2d_bytes": 5 * scalar}
+    if scorer == "fused":
+        # the first decision after an admission: the device pool uploaded
+        # once from the host cache (means and variances), the dirty 3-wide
+        # block's global ids and its readout's observation count; no block
+        # read back; the pick read back (index, score)
+        assert _sum_counts(recs, 3) == {
+            "pool_uploads": 1,
+            "host_syncs": 2,
+            "d2h_bytes": 2 * scalar,
+            "h2d_bytes": 2 * cap * f32 + 3 * 4 + scalar}
+        # the next: the readout's count up, the pick back
+        assert _sum_counts(recs, 5) == {
+            "host_syncs": 2, "d2h_bytes": 2 * scalar, "h2d_bytes": scalar}
+    else:
+        # the dirty 3-wide block read back (mean, variance) with its
+        # readout's observation count; the pool's upload (means, sds and
+        # the selected mask, and the device speed); the pick read back
+        # (index, score): every decision alike
+        pool = cap * (2 * f32 + 1) + scalar
+        for i in (3, 5):
+            assert _sum_counts(recs, i) == {
+                "host_syncs": 2 + 2,
+                "d2h_bytes": 2 * 3 * f32 + 2 * scalar,
+                "h2d_bytes": scalar + pool}
     assert tr.counts == {}
+
+
+def _pool_uploads(cp, tr, trace_id: int) -> int:
+    """The ``pool_uploads`` count of one decision, run as trace ``trace_id``."""
+    tr.begin_trace(trace_id)
+    assert cp.choose_mdmt() is not None
+    return _sum_counts(tr.records(), trace_id).get("pool_uploads", 0)
+
+
+@pytest.mark.parametrize("change", ["admit", "relocate"])
+def test_pool_uploads_once_after_a_layout_change(change):
+    """``pool_uploads`` reads 1 on the decision after an admission or a
+    relocation (the device pool is uploaded anew) and 0 on the next."""
+    cp = ControlPlane(np.random.default_rng(0), scorer="fused",
+                      num_shards=4, model_capacity=16, tenant_capacity=4)
+    tenants = [cp.add_tenant(*_block(3)) for _ in range(5)]
+    tr = Tracer()
+    cp.set_tracer(tr)
+    assert _pool_uploads(cp, tr, 0) == 1        # after set-up's admissions
+    assert _pool_uploads(cp, tr, 1) == 0
+    if change == "admit":
+        cp.add_tenant(*_block(2))
+    else:
+        for h in tenants[2:4]:         # shard 1 emptied, shard 0 full
+            cp.retire_tenant(h.tenant_id)
+        assert cp.compact(1.0)                  # at least one block moved
+    g = int(np.flatnonzero(~cp.selected)[0])
+    cp.record_start(g)
+    cp.record_observation(g, 0.2)
+    assert _pool_uploads(cp, tr, 2) == 1
+    assert _pool_uploads(cp, tr, 3) == 0
 
 
 def test_counts_land_on_the_innermost_open_span():
