@@ -1,6 +1,6 @@
 """Posterior flush per policy decision, in ms: the mean ``gp_flush`` span,
-the readback of the dirty GP blocks into the host cache (where the host
-first waits for the folds' device work)."""
+on the device scorers' path one readout dispatch per dirty GP block into
+the device-resident pool (nothing read back)."""
 
 
 def read(run):

@@ -3,13 +3,15 @@ BENCHMARK.json keeps the benchmark's contract."""
 
 import json
 import re
+import time
 from pathlib import Path
 
 import pytest
 
 from tinybench import tiny_root  # noqa: F401  (a fixture)
 from bench.harness import (BenchError, cell_metrics, find_cell,
-                           load_benchmark, metric_reader, peaks_for)
+                           load_benchmark, metric_reader, peaks_for,
+                           run_cell)
 
 ROOT = Path(__file__).resolve().parents[2]
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -99,6 +101,38 @@ def test_a_config_dropped_into_configs_is_picked_up(tiny_root):
                                "why": "a new cell"})
     _, cfg, traffic = find_cell(bench, "dropped-in.steady", tiny_root)
     assert cfg["name"] == "dropped-in" and traffic["pacing"] == "open_loop"
+
+
+def test_a_grid_config_dropped_into_configs_runs_and_is_correct(tiny_root):
+    # a categorical search space (3 tenants x a grid of four 3-level
+    # variables, the exponential Hamming kernel) as data files alone
+    bench = json.loads((tiny_root / "BENCHMARK.json").read_text())
+    grid = json.loads((tiny_root / "bench/configs/tiny-lc.json").read_text())
+    grid["name"] = "tiny-grid"
+    grid["tenants"].update(
+        arrivals={"kind": "at_start", "count": 3},
+        candidates={"kind": "fixed", "count": 81},
+        space={"points": "grid", "levels": [3, 3, 3, 3]},
+        kernel={"kind": "hamming", "length_scale": 1.0, "variance": 0.04})
+    (tiny_root / "bench/configs/tiny-grid.json").write_text(json.dumps(grid))
+    bench["workloads"].append({"name": "tiny-grid-saturated",
+                               "config": "tiny-grid", "traffic": "unpaced",
+                               "chips": 1, "why": "a grid cell"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if "tiny-lc-saturated" in m.get("workloads", []):
+            m["workloads"].append("tiny-grid-saturated")
+    (tiny_root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    def run(**kw):
+        return run_cell("tiny-grid-saturated", 2**33 + 7, 0.2, False,
+                        t_start=time.perf_counter(), require_tpu=False,
+                        root=tiny_root, **kw)
+    line, info = run()
+    assert line["correct"] is True and line["failed"] == 0
+    assert info["checked"] > 0 and info["live_models"] == 3 * 81
+    assert set(line["metrics"]) == {"setup_s", "decisions_per_s"}
+    line, info = run(control=True)
+    assert info["program"]["correct"] is True and line["correct"] is False
 
 
 def test_missing_files_and_unknown_devices_are_errors(tiny_root):

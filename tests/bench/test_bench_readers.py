@@ -39,7 +39,6 @@ def _view():
 @pytest.mark.parametrize("metric, expected", [
     ("fold_ms.saturated", 2.5),
     ("flush_ms.saturated", 1.2),
-    ("upload_ms.saturated", 1.8),
     ("host_syncs.saturated", 4.0),
     ("transfer_kb.saturated", (20 + 4 + 16_000 + 8_192 + 8 + 8) / 1024),
 ])
@@ -48,7 +47,6 @@ def test_reader_of_spans_and_counts(metric, expected):
 
 
 @pytest.mark.parametrize("metric", ["fold_ms.saturated", "flush_ms.saturated",
-                                    "upload_ms.saturated",
                                     "host_syncs.saturated",
                                     "transfer_kb.saturated"])
 def test_a_program_without_the_spans_or_counts_gives_no_reading(metric):
