@@ -318,7 +318,9 @@ class ControlPlane:
         come from the free pools when churn left any (slot reuse, DESIGN.md
         §10), else extend the space.  O(m) plus a mirror refresh; no other
         tenant's GP state is touched.  The block always lands inside one
-        shard span of the layout."""
+        shard span of the layout.  A float64 ``K_block`` that is the same
+        array object as a live tenant's passes through as that object, so
+        the two share one device prior (``BlockIncrementalGP.add_block``)."""
         if not self._dynamic:
             raise RuntimeError("churn is only supported on dynamic "
                                "ControlPlanes (not from_problem)")
@@ -339,7 +341,8 @@ class ControlPlane:
             self._num_tenants = max(self._num_tenants, tid + 1)
             self._num_models += m
             ids = np.arange(start, start + m, dtype=np.int64)
-            self._block_ids[tid] = self.gp.add_block(ids, K_block, mu0_block)
+            self._block_ids[tid] = self.gp.add_block(ids, K_block, mu0_block,
+                                                     tracer=tr)
             self.selected[ids] = False
             self.observed[ids] = False
             self.cost[ids] = cost_block
@@ -353,10 +356,10 @@ class ControlPlane:
             self._recompute_floor()
             self._rebuild_mirrors()
             if tr.enabled:
-                # the prior block (kernel, mean, jitter) uploaded at admission
+                # the prior block (kernel, mean, jitter) uploaded at
+                # admission, its kernel only where no live block shares it
+                # (counted by ``add_block``)
                 eng = self.gp._engines[self._block_ids[tid]]
-                tr.count("h2d_bytes",
-                         eng.K.nbytes + eng.mu0.nbytes + eng.jitter.nbytes)
                 tr.sync((eng.K, eng.mu0))
         return TenantHandle(tenant_id=tid, models=ids)
 
@@ -457,7 +460,8 @@ class ControlPlane:
         rebuilds ``W``/``alpha`` exactly.  The float32 readout cache is
         stored verbatim (plus the dirty set), so even entries of retired
         blocks — stale, always masked, but part of byte-level state — are
-        restored exactly."""
+        restored exactly.  Tenants that share a device prior get one host
+        array object, so :meth:`load_state` shares it again."""
         if not self._dynamic:
             raise RuntimeError("state_snapshot is only supported on dynamic "
                                "ControlPlanes (not from_problem)")
@@ -473,9 +477,13 @@ class ControlPlane:
             "cp/gp_var": self.gp._var.copy(),
         }
         bid_to_tid = {bid: tid for tid, bid in self._block_ids.items()}
+        priors: dict[int, np.ndarray] = {}  # prior key -> host copy
         for tid, bid in self._block_ids.items():
             eng = self.gp._engines[bid]
-            arrays[f"gp/{tid}/K"] = np.asarray(eng.K)
+            key = self.gp._prior_of[bid]
+            if key not in priors:
+                priors[key] = np.asarray(eng.K)
+            arrays[f"gp/{tid}/K"] = priors[key]
             arrays[f"gp/{tid}/mu0"] = np.asarray(eng.mu0)
             arrays[f"gp/{tid}/obs_idx"] = np.asarray(eng.observed, np.int64)
             arrays[f"gp/{tid}/obs_z"] = np.asarray(
@@ -761,7 +769,7 @@ class ControlPlane:
         self.observed[model] = True
         tr = self.tracer
         with tr.span("gp_fold", model=model):
-            self.gp.observe(model, z)
+            self.gp.observe(model, z, tr)
             if tr.enabled:
                 tr.count("h2d_bytes", FOLD_SCALARS * SCALAR_BYTES)
                 tr.sync(self.gp.fold_outputs(model))

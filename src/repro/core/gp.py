@@ -11,8 +11,10 @@ noise-free observations of a growing set of models.  Two engines are provided:
   maintains a Cholesky factor of the observed-set kernel and the matrix
   ``W = L^{-1} K[obs, :]`` so that appending one observation costs O(k * n)
   and the full posterior mean/variance over all n models is always available
-  in O(1) extra work.  All buffers are preallocated at size n so every
-  append is a fixed-shape jitted step (no recompilation as observations grow).
+  in O(1) extra work.  ``W`` holds a row capacity of rows, about doubled
+  (up to n) when a fold would write past its last row, so its memory
+  follows the observations; every other buffer is preallocated at size n.
+  Each append is a fixed-shape jitted step per row capacity.
 
 Observation noise is zero in the paper's setting (each model is run once);
 ``jitter`` keeps the Cholesky numerically PSD and is chosen far below any
@@ -31,6 +33,23 @@ from repro.obs.trace import NULL_TRACER, SCALAR_BYTES
 
 
 DEFAULT_JITTER = 1e-6
+
+#: ``W``'s row capacities are ``ROW_STEP * 2**j + 8`` (136, 264, 520, ...)
+#: up to the block's size n: a fold that would write past the last row
+#: first grows them to the next.  8 rows past a multiple of 128, a
+#: (rows, n) buffer keeps the TPU's default row-major layout, which the
+#: fold's row loop reads; with a multiple of 128 rows and n not one, the
+#: TPU lays it out column-major, and every fold and readout would copy it
+#: (tests/test_tpu_compile.py)
+ROW_STEP = 128
+
+
+def row_capacities(n: int) -> list[int]:
+    """Every row capacity ``W`` of an n-model engine takes, in order."""
+    caps: list[int] = []
+    while not caps or caps[-1] < n:
+        caps.append(min(ROW_STEP * 2 ** len(caps) + 8, n))
+    return caps
 
 
 def posterior_masked(
@@ -102,7 +121,7 @@ def _append_step(
 ):
     """One fixed-shape incremental Cholesky/posterior update.
 
-    W:        (n, n) buffer; rows [0, k) hold L^{-1} K[obs, :].
+    W:        (r, n) buffer, k < r <= n; rows [0, k) hold L^{-1} K[obs, :].
     alpha:    (n,) buffer; entries [0, k) hold L^{-1} (z_obs - mu0_obs).
     diag_acc: (n,) running sum of W^2 over observed rows (= prior_var - post_var).
     K_row:    (n,) row of the prior kernel for the new model.
@@ -114,7 +133,8 @@ def _append_step(
     (DESIGN.md §14).  The extra output changes no numerics: W/alpha/
     diag_acc are computed exactly as before.
     """
-    # l = L^{-1} K[obs, new] is exactly column `idx` of W (rows >= k are zero).
+    # l = L^{-1} K[obs, new] is exactly column `idx` of W (rows >= k are
+    # zero, so W's row capacity changes no sum).
     l = W[:, idx]
     d2 = K_row[idx] + jitter - rows_dot(l, l, k)
     d = jnp.sqrt(jnp.maximum(d2, jitter))
@@ -126,10 +146,16 @@ def _append_step(
     return W, alpha, diag_acc, d2
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def _grow_rows(W, rows):
+    """``W``'s rows at the top of a zeroed (rows, n) buffer."""
+    return jnp.pad(W, ((0, rows - W.shape[0]), (0, 0)))
+
+
 @jax.jit
 def _readout(W, alpha, mu0, kdiag, diag_acc, k):
     # alpha @ W over the k observed rows (not W.T @ alpha): keeps the
-    # (n, n) buffer row-major and avoids an eager 25MB transpose copy per
+    # (r, n) buffer row-major and avoids an eager transpose copy per
     # scheduler decision.
     mu = mu0 + rows_dot(alpha, W, k)
     var = jnp.maximum(kdiag - diag_acc, 0.0)
@@ -148,6 +174,42 @@ def _readout_into(mu_pool, var_pool, sd_pool, idx, W, alpha, mu0, kdiag,
             sd_pool.at[idx].set(jnp.sqrt(var)))
 
 
+def _compile(program, *args) -> None:
+    """Compile the jitted ``program`` for ``args`` (arrays, or shapes)
+    ahead of its first call.  Its lowering is cached by the arguments'
+    abstract values, so a later call with arrays of those shapes finds the
+    executable and compiles nothing.  A program replaced by a plain
+    function has nothing to compile."""
+    lower = getattr(program, "lower", None)
+    if lower is not None:
+        lower(*args).compile()
+
+
+def _compile_growth(n: int, dtype) -> None:
+    """Compile what only ``W``'s growth reaches in an n-model engine: the
+    fold at every row capacity after the first, and each growth.  The
+    first capacity compiles at the first fold, as any first shape."""
+    caps = row_capacities(n)
+    vec = jax.ShapeDtypeStruct((n,), dtype)
+    scalar, index = jnp.zeros((), dtype), jnp.asarray(0)
+    for rows, grown in zip(caps, caps[1:]):
+        _compile(_grow_rows, jax.ShapeDtypeStruct((rows, n), dtype), grown)
+        _compile(_append_step, jax.ShapeDtypeStruct((grown, n), dtype), vec,
+                 vec, vec, index, scalar, scalar, index, scalar)
+
+
+def _compile_readouts(pool: int, n: int, dtype) -> None:
+    """Compile an n-model block's readout into a pool of ``pool`` slots
+    (``_readout_into``) at every row capacity after the first."""
+    slots = jax.ShapeDtypeStruct((pool,), jnp.float32)
+    idx = jax.ShapeDtypeStruct((n,), jnp.int32)
+    vec = jax.ShapeDtypeStruct((n,), dtype)
+    for rows in row_capacities(n)[1:]:
+        _compile(_readout_into, slots, slots, slots, idx,
+                 jax.ShapeDtypeStruct((rows, n), dtype), vec, vec, vec, vec,
+                 jnp.asarray(0))
+
+
 class IncrementalGP:
     """Incremental zero-noise GP posterior over a fixed finite model set."""
 
@@ -162,7 +224,7 @@ class IncrementalGP:
         self.n = n
         self.jitter = jnp.asarray(jitter, dtype=self.K.dtype)
         dtype = self.K.dtype
-        self._W = jnp.zeros((n, n), dtype=dtype)
+        self._W = jnp.zeros((row_capacities(n)[0], n), dtype=dtype)
         self._alpha = jnp.zeros((n,), dtype=dtype)
         self._diag_acc = jnp.zeros((n,), dtype=dtype)
         self._k = 0
@@ -173,8 +235,11 @@ class IncrementalGP:
         # unless a health monitor asks — the disabled path stays async)
         self.last_d2 = None
 
-    def observe(self, idx: int, z_val: float) -> None:
-        """Condition on z(model idx) = z_val.  O(n^2) fixed-shape jitted step."""
+    def observe(self, idx: int, z_val: float, tracer=NULL_TRACER) -> None:
+        """Condition on z(model idx) = z_val: one fixed-shape jitted step
+        that loops over the k observed rows (counted as ``fold_rows``),
+        after growing ``W`` to its next row capacity if row k is past its
+        last (a ``gp_grow`` span)."""
         if idx in self._z:
             raise ValueError(f"model {idx} already observed")
         import math
@@ -183,6 +248,14 @@ class IncrementalGP:
             # would silently corrupt every later posterior readout
             raise ValueError(f"non-finite observation {z_val!r} for "
                              f"model {idx}")
+        rows = self._W.shape[0]
+        if self._k == rows:
+            caps = row_capacities(self.n)
+            grown = caps[caps.index(rows) + 1]
+            with tracer.span("gp_grow", rows_from=rows, rows_to=grown):
+                self._W = tracer.sync(_grow_rows(self._W, grown))
+        if tracer.enabled:
+            tracer.count("fold_rows", self._k)
         self._W, self._alpha, self._diag_acc, self.last_d2 = _append_step(
             self._W,
             self._alpha,
@@ -210,17 +283,20 @@ class IncrementalGP:
     def resource_stats(self) -> dict:
         """Analytic byte/observation accounting of this engine's buffers
         (obs/accounting.py introspects through here, never through the
-        private attributes).  ``alloc_bytes`` is the preallocated footprint
-        — W (n,n) + K (n,n) + alpha/diag_acc/mu0 (n,) each; ``active_bytes``
-        is the Cholesky-occupied share, the O(k·n) rows [0, k) of W plus k
-        entries of alpha — the part that grows O(obs²) when n tracks the
-        observed set."""
+        private attributes).  ``alloc_bytes`` is the allocated footprint
+        — W (rows, n) at its row capacity + K (n, n), of which
+        ``prior_bytes`` (shared between engines given one prior) +
+        alpha/diag_acc/mu0 (n,) each; ``active_bytes`` is the
+        Cholesky-occupied share, the O(k·n) rows [0, k) of W plus k
+        entries of alpha."""
         item = self.K.dtype.itemsize
-        n, k = self.n, self._k
+        n, k, rows = self.n, self._k, self._W.shape[0]
         return {
             "models": n,
             "obs": k,
-            "alloc_bytes": (2 * n * n + 3 * n) * item,
+            "rows": rows,
+            "alloc_bytes": (rows * n + n * n + 3 * n) * item,
+            "prior_bytes": n * n * item,
             "active_bytes": (k * n + k) * item,
             "dtype_bytes": item,
         }
@@ -269,6 +345,11 @@ class BlockIncrementalGP:
     saw them.  A change of the host cache's layout (admission, relocation,
     growth, a restore) drops the pool; the next device flush uploads the
     host cache once and recomputes the blocks it lacks.
+
+    Blocks given one prior array object share its device copy: it is
+    uploaded at the first such admission and freed with the last of its
+    blocks.  Contents are never compared; a caller shares a prior by
+    handing over the same object.
     """
 
     def __init__(self, K=None, mu0=None, blocks: list | None = None,
@@ -286,6 +367,13 @@ class BlockIncrementalGP:
         self._pool = None       # device (mu, var, sd); None: upload anew
         self._dirty_dev: set[int] = set()
         self._idx_dev: dict[int, jax.Array] = {}    # block's global ids
+        # priors on the device by id() of the host array: (the host array,
+        # held so that its id stays its own, and its device copy)
+        self._priors: dict[int, tuple] = {}
+        self._prior_of: dict[int, int] = {}         # block id -> its key
+        # (n, dtype) whose growth and (pool, n, dtype) whose readouts are
+        # compiled ahead
+        self._compiled: set[tuple] = set()
         self.observed: list[int] = []
         self._z = {}
         self.last_d2 = None     # pivot d² of the most recent fold
@@ -321,10 +409,14 @@ class BlockIncrementalGP:
         self.n = n_cap
         self._pool = None
 
-    def add_block(self, indices, K_block, mu0_block) -> int:
+    def add_block(self, indices, K_block, mu0_block,
+                  tracer=NULL_TRACER) -> int:
         """Register one tenant's covariance block at the given global model
-        indices.  O(m) setup; no other block is touched.  Returns a block id
-        for :meth:`retire_block`."""
+        indices.  O(m) setup; no other block is touched.  A ``K_block``
+        already on the device (the same object as a live block's) is not
+        uploaded again: ``prior_shared`` counts 1, else 0.  The first block
+        of a size compiles what its ``W``'s growth reaches.  Returns a
+        block id for :meth:`retire_block`."""
         import numpy as np
         b = np.asarray(indices, dtype=np.int64)
         K_block = np.asarray(K_block)
@@ -337,7 +429,19 @@ class BlockIncrementalGP:
         self._next_block_id += 1
         self.ensure_capacity(int(b.max()) + 1)
         self._blocks[bid] = b
-        self._engines[bid] = IncrementalGP(K_block, mu0_block, self._jitter)
+        shared = id(K_block) in self._priors
+        if not shared:
+            self._priors[id(K_block)] = (K_block, jnp.asarray(K_block))
+        self._prior_of[bid] = id(K_block)
+        eng = self._engines[bid] = IncrementalGP(
+            self._priors[id(K_block)][1], mu0_block, self._jitter)
+        if tracer.enabled:
+            tracer.count("prior_shared", int(shared))
+            tracer.count("h2d_bytes", (0 if shared else eng.K.nbytes)
+                         + eng.mu0.nbytes + eng.jitter.nbytes)
+        if (m, eng.K.dtype) not in self._compiled:
+            _compile_growth(m, eng.K.dtype)
+            self._compiled.add((m, eng.K.dtype))
         for li, g in enumerate(b.tolist()):
             self._local[int(g)] = (bid, li)
         self._mu[b] = mu0_block.astype(np.float32)
@@ -351,6 +455,9 @@ class BlockIncrementalGP:
         (no refactorization).  Cached readout entries go stale — mask them."""
         b = self._blocks.pop(block_id)
         self._engines.pop(block_id)
+        key = self._prior_of.pop(block_id)
+        if key not in self._prior_of.values():
+            del self._priors[key]       # its last block: free the prior
         self._dirty.discard(block_id)
         self._dirty_dev.discard(block_id)
         self._idx_dev.pop(block_id, None)
@@ -403,7 +510,7 @@ class BlockIncrementalGP:
             return None
         return blocks
 
-    def observe(self, idx: int, z_val: float) -> None:
+    def observe(self, idx: int, z_val: float, tracer=NULL_TRACER) -> None:
         import math
         if not math.isfinite(z_val):
             # poisoned-observation guard at the block boundary too: callers
@@ -413,7 +520,7 @@ class BlockIncrementalGP:
         if idx not in self._local:
             raise KeyError(f"model {idx} belongs to no live block")
         bi, li = self._local[idx]
-        self._engines[bi].observe(li, z_val)
+        self._engines[bi].observe(li, z_val, tracer)
         self.last_d2 = self._engines[bi].last_d2
         self._dirty.add(bi)
         self._dirty_dev.add(bi)
@@ -440,6 +547,8 @@ class BlockIncrementalGP:
         ``blocks`` maps block id -> the owning :class:`IncrementalGP`'s
         :meth:`~IncrementalGP.resource_stats`; the aggregate adds the host
         readout caches (``_mu``/``_var``, float32 over the full capacity).
+        A prior shared by several blocks counts once in the aggregate
+        ``alloc_bytes`` (``priors`` counts the distinct ones).
         Pure host-side introspection: no device syncs, so the accounting
         plane's disabled-path cost discipline holds."""
         blocks = {bid: eng.resource_stats()
@@ -447,9 +556,12 @@ class BlockIncrementalGP:
         return {
             "blocks": blocks,
             "num_blocks": len(blocks),
+            "priors": len(self._priors),
             "capacity": self.n,
             "obs_total": sum(b["obs"] for b in blocks.values()),
-            "alloc_bytes": sum(b["alloc_bytes"] for b in blocks.values()),
+            "alloc_bytes": sum(b["alloc_bytes"] - b["prior_bytes"]
+                               for b in blocks.values())
+            + sum(p[1].nbytes for p in self._priors.values()),
             "active_bytes": sum(b["active_bytes"] for b in blocks.values()),
             "readout_bytes": self.readout_nbytes,
         }
@@ -461,12 +573,14 @@ class BlockIncrementalGP:
         import numpy as np
         with tracer.span("gp_flush", blocks=len(self._dirty)):
             for bi in self._dirty:
-                mu_b, var_b = self._engines[bi].posterior()
+                eng = self._engines[bi]
+                mu_b, var_b = eng.posterior()
                 b = self._blocks[bi]
                 mu_h, var_h = np.asarray(mu_b), np.asarray(var_b)
                 self._mu[b] = mu_h
                 self._var[b] = var_h
                 if tracer.enabled:
+                    tracer.count("readout_rows", eng.num_observed)
                     tracer.count("host_syncs", 2)
                     tracer.count("h2d_bytes", SCALAR_BYTES)  # readout's k
                     tracer.count("d2h_bytes", mu_h.nbytes + var_h.nbytes)
@@ -488,8 +602,16 @@ class BlockIncrementalGP:
         the pool last saw it, under a ``gp_flush`` span (attr ``blocks``),
         with nothing read back.  A dropped pool is first uploaded from the
         host cache under ``posterior_upload`` (the sqrt on the device), and
-        counted as ``pool_uploads`` on the enclosing span."""
+        counted as ``pool_uploads`` on the enclosing span; before it, the
+        readouts of the live blocks' grown row capacities into a pool of
+        this size are compiled.  Each readout loops over its block's
+        observed rows, counted as ``readout_rows``."""
         if self._pool is None:
+            for eng in self._engines.values():
+                key = (self.n, eng.n, eng.K.dtype)
+                if key not in self._compiled:
+                    _compile_readouts(*key)
+                    self._compiled.add(key)
             with tracer.span("posterior_upload"):
                 mu, var = jnp.asarray(self._mu), jnp.asarray(self._var)
                 self._pool = (mu, var, jnp.sqrt(var))
@@ -508,9 +630,11 @@ class BlockIncrementalGP:
                     self._idx_dev[bi] = idx
                     if tracer.enabled:
                         tracer.count("h2d_bytes", idx.nbytes)
-                self._pool = _readout_into(
-                    *self._pool, idx, *self._engines[bi].readout_args())
+                eng = self._engines[bi]
+                self._pool = _readout_into(*self._pool, idx,
+                                           *eng.readout_args())
                 if tracer.enabled:
+                    tracer.count("readout_rows", eng.num_observed)
                     tracer.count("h2d_bytes", SCALAR_BYTES)  # readout's k
             self._dirty_dev.clear()
         return self._pool

@@ -25,8 +25,11 @@ the control plane it instruments (DESIGN.md §13):
   to the ``counts`` of the innermost open span's record (or to the
   tracer's root ``counts`` when no span is open).  The program counts
   ``host_syncs`` (blocking device→host readbacks the untraced path makes;
-  the tracer's own ``sync`` is not one) and ``h2d_bytes`` / ``d2h_bytes``
-  (arrays and Python scalars moved across the boundary, scalars at 4 B).
+  the tracer's own ``sync`` is not one), ``h2d_bytes`` / ``d2h_bytes``
+  (arrays and Python scalars moved across the boundary, scalars at 4 B),
+  ``fold_rows`` / ``readout_rows`` (the observed rows each GP fold and
+  readout looped over) and ``prior_shared`` (an admission whose prior was
+  already on the device).
   Call sites guard with ``if tracer.enabled`` so an untraced run computes
   no byte count.  Counts are not part of ``signature()``.
 

@@ -1068,6 +1068,13 @@ class StreamEngine:
             retired in meta["fleet"]]
 
         self.telemetry.load_state(meta["telemetry"])
+        # each live tenant's prior is its arrival's block object (equal to
+        # the snapshot's copy), so tenants that shared a device prior, and
+        # those still to arrive with the same block, share it again
+        arrays = dict(arrays)
+        for tr in self._tenants.values():
+            if tr.tenant_id is not None and not tr.departed:
+                arrays[f"gp/{tr.tenant_id}/K"] = tr.arrive.K_block
         self.cp.load_state(arrays, meta["cp"])
         self._restore_extra(meta["extra"])
         # tolerant restore: snapshots from health-less runs lack the key

@@ -10,7 +10,11 @@ event sequence.  This module makes that fold *durable* and *restartable*
     - **external** events (:class:`~repro.stream.workload.TenantArrive` /
       ``TenantDepart`` / ``SliceFail`` / ``DeviceJoin`` / ``DeviceLeave`` /
       ``DevicePreempt``), serialized losslessly (float64 arrays round-trip
-      exactly through JSON's repr-based floats) — the replayable input;
+      exactly through JSON's repr-based floats) — the replayable input.
+      An arrival whose prior block is the same array object as an earlier
+      arrival's is written as a reference to it (``K_block_of``: that
+      tenant's key) and read back as one shared object, so a recovered
+      run's tenants share device priors as the original run's did;
     - **processed** records ``(index, t, kind, payload)`` — one per heap pop
       the engine handled, in order.  These are the *audit* stream: a restored
       engine regenerates the suffix, and any divergence from the pre-crash
@@ -76,16 +80,25 @@ LOG_SCHEMA_VERSION = 1
 # timestamp survives serialize -> deserialize bit-identically — the replay
 # oracle's byte-identical claim rests on this.
 
-def serialize_event(ev: Event) -> dict:
+def serialize_event(ev: Event, blocks: dict | None = None) -> dict:
+    """``ev`` as a JSON-ready dict.  ``blocks`` (``id(K_block)`` -> the
+    tenant key that first wrote it) makes an arrival whose prior block was
+    written before a reference to it; its caller keeps those blocks alive,
+    so their ids stay their own."""
     if not isinstance(ev, (TenantArrive, TenantDepart, SliceFail,
                            DeviceJoin, DeviceLeave, DevicePreempt,
                            TrialHang, TrialPoison, MeshShrink)):
         raise TypeError(f"unknown event {ev!r}")
     d: dict = {"type": type(ev).__name__, "at": float(ev.at)}
     if isinstance(ev, TenantArrive):
-        d.update(tenant_key=int(ev.tenant_key),
-                 K_block=np.asarray(ev.K_block, np.float64).tolist(),
-                 mu0=np.asarray(ev.mu0, np.float64).tolist(),
+        first = (int(ev.tenant_key) if blocks is None else
+                 blocks.setdefault(id(ev.K_block), int(ev.tenant_key)))
+        d.update(tenant_key=int(ev.tenant_key))
+        if first == ev.tenant_key:
+            d.update(K_block=np.asarray(ev.K_block, np.float64).tolist())
+        else:
+            d.update(K_block_of=first)
+        d.update(mu0=np.asarray(ev.mu0, np.float64).tolist(),
                  cost=np.asarray(ev.cost, np.float64).tolist(),
                  z_true=np.asarray(ev.z_true, np.float64).tolist())
     elif isinstance(ev, TenantDepart):
@@ -103,12 +116,20 @@ def serialize_event(ev: Event) -> dict:
     return d
 
 
-def deserialize_event(d: dict) -> Event:
+def deserialize_event(d: dict, blocks: dict | None = None) -> Event:
+    """Inverse of :func:`serialize_event`; ``blocks`` (tenant key -> its
+    prior block) resolves a ``K_block_of`` reference and gains each
+    block read."""
     t = d["type"]
     if t == "TenantArrive":
+        if "K_block_of" in d:
+            K = blocks[d["K_block_of"]]
+        else:
+            K = np.asarray(d["K_block"], np.float64)
+        if blocks is not None:
+            blocks[d["tenant_key"]] = K
         return TenantArrive(
-            at=d["at"], tenant_key=d["tenant_key"],
-            K_block=np.asarray(d["K_block"], np.float64),
+            at=d["at"], tenant_key=d["tenant_key"], K_block=K,
             mu0=np.asarray(d["mu0"], np.float64),
             cost=np.asarray(d["cost"], np.float64),
             z_true=np.asarray(d["z_true"], np.float64))
@@ -150,6 +171,7 @@ class EventLog:
         self.processed: list[tuple[int, float, str, list]] = []
         self.alerts: list[dict] = []
         self._ext_f = self._proc_f = self._alert_f = None
+        self._written_blocks: dict[int, int] = {}   # serialize_event's
         if self.path is not None:
             self.path.mkdir(parents=True, exist_ok=True)
             self._write_meta()
@@ -167,7 +189,8 @@ class EventLog:
     def append_external(self, ev: Event) -> None:
         self.external.append(ev)
         if self._ext_f is not None:
-            self._ext_f.write(json.dumps(serialize_event(ev)) + "\n")
+            self._ext_f.write(json.dumps(
+                serialize_event(ev, self._written_blocks)) + "\n")
             self._ext_f.flush()
 
     def append_processed(self, index: int, t: float, kind: str,
@@ -218,8 +241,9 @@ class EventLog:
         log.meta = meta
         ext = path / "external.jsonl"
         if ext.exists():
+            blocks: dict = {}
             with open(ext) as f:
-                log.external = [deserialize_event(json.loads(line))
+                log.external = [deserialize_event(json.loads(line), blocks)
                                 for line in f if line.strip()]
         proc = path / "processed.jsonl"
         if proc.exists():

@@ -447,13 +447,18 @@ def test_counts_of_a_decision_follow_from_its_shapes(scorer):
     mirrors = slots * cap + cap * f32 + cap + slots * f32
     if scorer == "sharded":                 # its own membership and costs
         mirrors += slots * cap + cap * f32
+    # a prior of its own, uploaded
     assert _sum_counts(recs, 0) == {
+        "prior_shared": 0,
         "h2d_bytes": m * m * f32 + m * f32 + scalar + mirrors}
     assert _sum_counts(recs, 1) == {"h2d_bytes": 2 * scalar}
-    # the fold's five scalars, and the first incumbent of tenant 0
-    assert _sum_counts(recs, 2) == {"h2d_bytes": 5 * scalar + 2 * scalar}
-    # a fold that improves no incumbent: its five scalars alone
-    assert _sum_counts(recs, 4) == {"h2d_bytes": 5 * scalar}
+    # the fold's five scalars, and the first incumbent of tenant 0; the
+    # block's first fold loops over no row
+    assert _sum_counts(recs, 2) == {"fold_rows": 0,
+                                    "h2d_bytes": 5 * scalar + 2 * scalar}
+    # a fold that improves no incumbent: its five scalars alone, over the
+    # block's one observed row
+    assert _sum_counts(recs, 4) == {"fold_rows": 1, "h2d_bytes": 5 * scalar}
     if scorer == "fused":
         # the first decision after an admission: the device pool uploaded
         # once from the host cache (means and variances), the dirty 3-wide
@@ -461,20 +466,23 @@ def test_counts_of_a_decision_follow_from_its_shapes(scorer):
         # read back; the pick read back (index, score)
         assert _sum_counts(recs, 3) == {
             "pool_uploads": 1,
+            "readout_rows": 1,
             "host_syncs": 2,
             "d2h_bytes": 2 * scalar,
             "h2d_bytes": 2 * cap * f32 + 3 * 4 + scalar}
         # the next: the readout's count up, the pick back
         assert _sum_counts(recs, 5) == {
-            "host_syncs": 2, "d2h_bytes": 2 * scalar, "h2d_bytes": scalar}
+            "readout_rows": 2, "host_syncs": 2, "d2h_bytes": 2 * scalar,
+            "h2d_bytes": scalar}
     else:
         # the dirty 3-wide block read back (mean, variance) with its
         # readout's observation count; the pool's upload (means, sds and
         # the selected mask, and the device speed); the pick read back
-        # (index, score): every decision alike
+        # (index, score): every decision alike but for the block's rows
         pool = cap * (2 * f32 + 1) + scalar
-        for i in (3, 5):
+        for i, rows in ((3, 1), (5, 2)):
             assert _sum_counts(recs, i) == {
+                "readout_rows": rows,
                 "host_syncs": 2 + 2,
                 "d2h_bytes": 2 * 3 * f32 + 2 * scalar,
                 "h2d_bytes": scalar + pool}

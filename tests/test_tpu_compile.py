@@ -124,3 +124,28 @@ def test_sharded_decision_compiles_on_v5e_2x2_mesh(topo, kernel,
     # all-reduce of dynamic-update-slices
     assert re.search(r"all-(gather|reduce)", text)
     assert ("tpu_custom_call" in text) == (kernel == "pallas_topk")
+
+
+@pytest.mark.parametrize("m", [2_000, 15_625])
+def test_W_keeps_a_row_major_layout_at_every_row_capacity(one_chip, m):
+    """The TPU's default layout of each (rows, m) buffer W takes is
+    row-major, as the fold's row loop reads it, so no fold or readout
+    copies W (at 2,048 rows the TPU would lay W out column-major)."""
+    from repro.core import gp
+
+    def layout(rows):
+        shape = _sds((rows, m), jnp.float32, one_chip)
+        compiled = jax.jit(lambda W: W).lower(shape).compile()
+        return compiled.input_formats[0][0].layout.major_to_minor
+
+    assert layout(2_048) == (1, 0)
+    for rows in gp.row_capacities(m):
+        assert layout(rows) == (0, 1), rows
+    rows = gp.row_capacities(m)[1]
+    vec, i32 = _sds((m,), jnp.float32, one_chip), jnp.int32
+    fold = gp._append_step.lower(
+        _sds((rows, m), jnp.float32, one_chip), vec, vec, vec,
+        _sds((), i32, one_chip), _sds((), jnp.float32, one_chip),
+        _sds((), jnp.float32, one_chip), _sds((), i32, one_chip),
+        _sds((), jnp.float32, one_chip)).compile()
+    assert fold.memory_analysis().temp_size_in_bytes < rows * m * 4
